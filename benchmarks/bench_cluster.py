@@ -89,7 +89,8 @@ def _spawn(code: str, **fmt) -> subprocess.Popen:
     return subprocess.Popen(
         [sys.executable, "-c", code % fmt],
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
-        env={**os.environ, "PYTHONPATH": SRC, "REPRO_TRAIN_DEVICES": "4"})
+        env={**os.environ, "PYTHONPATH": SRC, "REPRO_TRAIN_DEVICES": "4",
+             "JAX_PLATFORMS": "cpu"})
 
 
 def _collect(proc: subprocess.Popen, who: str, timeout: int = 1800) -> dict:

@@ -43,7 +43,8 @@ def _run_child(steps: int, micro: int) -> dict:
     proc = subprocess.run(
         [sys.executable, "-c", _CHILD % {"steps": steps, "micro": micro}],
         capture_output=True, text=True, timeout=1800,
-        env={**os.environ, "PYTHONPATH": SRC, "REPRO_TRAIN_DEVICES": "4"})
+        env={**os.environ, "PYTHONPATH": SRC, "REPRO_TRAIN_DEVICES": "4",
+             "JAX_PLATFORMS": "cpu"})
     if proc.returncode != 0:
         raise RuntimeError(f"elastic bench child failed:\n"
                            f"{proc.stdout}\n{proc.stderr[-3000:]}")

@@ -202,7 +202,8 @@ def run_obs(quick: bool = False):
     out = subprocess.run(
         [sys.executable, "-c", _OBS_CHILD % {"steps": 10 if quick else 24}],
         capture_output=True, text=True, timeout=1800,
-        env={**os.environ, "PYTHONPATH": src, "REPRO_TRAIN_DEVICES": "4"})
+        env={**os.environ, "PYTHONPATH": src, "REPRO_TRAIN_DEVICES": "4",
+             "JAX_PLATFORMS": "cpu"})
     if out.returncode != 0:
         raise RuntimeError(f"obs step bench failed:\n{out.stdout[-2000:]}"
                            f"\n{out.stderr[-2000:]}")

@@ -81,7 +81,8 @@ def _run_child(gen_long: int, d_model: int) -> dict:
         [sys.executable, "-c", _CHILD % {
             "gen_long": gen_long, "d_model": d_model}],
         capture_output=True, text=True, timeout=1800,
-        env={**os.environ, "PYTHONPATH": SRC, "REPRO_TRAIN_DEVICES": "4"})
+        env={**os.environ, "PYTHONPATH": SRC, "REPRO_TRAIN_DEVICES": "4",
+             "JAX_PLATFORMS": "cpu"})
     if proc.returncode != 0:
         raise RuntimeError(f"serve bench child failed:\n"
                            f"{proc.stdout}\n{proc.stderr[-3000:]}")
@@ -213,7 +214,8 @@ def _run_paged_child(requests: int, layers: int, d_model: int) -> dict:
         [sys.executable, "-c", _CHILD_PAGED % {
             "requests": requests, "layers": layers, "d_model": d_model}],
         capture_output=True, text=True, timeout=1800,
-        env={**os.environ, "PYTHONPATH": SRC, "REPRO_TRAIN_DEVICES": "4"})
+        env={**os.environ, "PYTHONPATH": SRC, "REPRO_TRAIN_DEVICES": "4",
+             "JAX_PLATFORMS": "cpu"})
     if proc.returncode != 0:
         raise RuntimeError(f"paged bench child failed:\n"
                            f"{proc.stdout}\n{proc.stderr[-3000:]}")

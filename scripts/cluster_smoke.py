@@ -41,7 +41,10 @@ sys.path.insert(0, SRC)
 
 from repro.cluster.http_rpc import HttpJobManager, spawn_http_manager  # noqa: E402
 
-ENV = {**os.environ, "PYTHONPATH": SRC, "REPRO_TRAIN_DEVICES": "4"}
+# both tenants simulate on virtual CPU devices: on a real host two tenant
+# processes cannot share the chips one process holds
+ENV = {**os.environ, "PYTHONPATH": SRC, "REPRO_TRAIN_DEVICES": "4",
+       "JAX_PLATFORMS": "cpu"}
 
 
 def _spawn_cli(module: str, args: list, log_path: str) -> subprocess.Popen:

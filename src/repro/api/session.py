@@ -86,6 +86,7 @@ class Session:
         self._jm_dir = None
         self._closed = False
         self.injector = None     # faults.ChaosInjector when chaos is on
+        self.state = None        # the trainer's final EngineState
         self._resume_dir: Optional[str] = None
         self._resume_step: Optional[int] = None
         # ---- observability (DESIGN.md §15) --------------------------------
@@ -110,6 +111,13 @@ class Session:
         s._resume_step = int(idx["step"])
         return s
 
+    @property
+    def engine(self):
+        """The ``ElasticEngine`` of the last train/serve run (None before)."""
+        if self._server is not None:
+            return self._server.engine
+        return self._engine
+
     # -- lifecycle ---------------------------------------------------------
     def __enter__(self) -> "Session":
         return self
@@ -121,6 +129,7 @@ class Session:
         if self._closed:
             return
         self._closed = True
+        self.state = None        # release the device buffers
         self._obs_end()
         if self._cp is not None:
             self._cp.close()
@@ -191,7 +200,7 @@ class Session:
             self.metrics.save(obs.metrics_out)
 
     # -- shared assembly ---------------------------------------------------
-    def _model_config(self):
+    def model_config(self):
         from repro.configs.base import get_config, reduced_config
         m = self.spec.model
         cfg = get_config(m.arch)
@@ -340,7 +349,7 @@ class Session:
                 "(cluster.autoscale / --autoscale)", DeprecationWarning,
                 stacklevel=2)
 
-        cfg = self._model_config()
+        cfg = self.model_config()
         dcfg = self._dist_config()
         dyncfg = spec.dynamics.to_config()
         shapes = PipelineShapes(num_micro=spec.parallel.num_micro,
@@ -569,8 +578,10 @@ class Session:
                                    stages=state.stages)
                        if tracer is not None else None)
             loss, stats, gnorm = engine.step(state, batch, lr)
-            # one scalar sync for the loss curve; the full per-slot stats
-            # tree stays on device until controller cadence (§3.3.1)
+            # the step time ends when the device has finished the update;
+            # the per-slot stats tree stays on device until controller
+            # cadence (§3.3.1)
+            jax.block_until_ready((loss, state.params, state.opt_state))
             losses.append(float(loss))
             if sp_step is not None:
                 sp_step.end(compiled=engine.last_step_compiled)
@@ -963,6 +974,7 @@ class Session:
                      "breaker": jm.breaker.state_dict()}
                     if jm is not None else None),
         }
+        self.state = state
         self._emit("train_summary", steps - 1,
                    loss_first=losses[0] if losses else None,
                    loss_last=losses[-1] if losses else None,
@@ -978,7 +990,7 @@ class Session:
         arrivals, mixed prompt/gen lengths, optional early-exit fraction)."""
         from repro.serve import make_trace
         s = self.spec.serve
-        cfg = self._model_config()
+        cfg = self.model_config()
         return make_trace(s.requests, prompt_len=s.prompt_len,
                           max_gen=s.gen, vocab_size=cfg.vocab_size,
                           seed=self.spec.seed,
@@ -1000,7 +1012,7 @@ class Session:
         spec = self.spec
         s = spec.serve
         tracer = self._obs_begin("serve")
-        cfg = self._model_config()
+        cfg = self.model_config()
         dcfg = self._dist_config()
         dyncfg = spec.dynamics.to_config()
         shapes = PipelineShapes(spec.parallel.num_micro,

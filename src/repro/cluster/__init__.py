@@ -11,23 +11,7 @@ the job manager.
                steal/yield, safe-point preemption) above one WorkerPool
   http_rpc   — HTTP transport for the scheduler (stdlib http.server),
                so N Sessions in N processes contend over one manager
-"""
-from repro.cluster.autoscaler import (Autoscaler, AutoscalerConfig,
-                                      ScaleDecision)
-from repro.cluster.http_rpc import (HttpJobManager, serve_http_manager,
-                                    spawn_http_manager)
-from repro.cluster.rpc import (FileJobManager, InProcessJobManager,
-                               JobManagerClient, TenantVerbsMixin,
-                               serve_file_manager, spawn_file_manager)
-from repro.cluster.scheduler import (ClusterScheduler,
-                                     SchedulerInvariantError, Tenant)
-from repro.cluster.service import ControlPlane, DecisionPlan, StatsSnapshot
 
-__all__ = [
-    "Autoscaler", "AutoscalerConfig", "ScaleDecision",
-    "ControlPlane", "DecisionPlan", "StatsSnapshot",
-    "JobManagerClient", "InProcessJobManager", "FileJobManager",
-    "TenantVerbsMixin", "serve_file_manager", "spawn_file_manager",
-    "ClusterScheduler", "SchedulerInvariantError", "Tenant",
-    "HttpJobManager", "serve_http_manager", "spawn_http_manager",
-]
+Import the submodules directly.  The package itself imports nothing, so
+the job-manager servers (``rpc``, ``http_rpc``) start without JAX.
+"""

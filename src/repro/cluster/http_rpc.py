@@ -314,7 +314,9 @@ def spawn_http_manager(run_dir: str, workers: int, *, spares: int = 0,
                        ) -> Tuple[subprocess.Popen, str]:
     """Start the HTTP job manager as a separate process and return
     ``(proc, url)`` once it is accepting connections.  The idle timeout is
-    a safety net so an orphaned server never outlives its job by much."""
+    a safety net so an orphaned server never outlives its job by much.
+    The server does no device work, so it is pinned to the CPU platform:
+    the trainer process owns the accelerator."""
     os.makedirs(run_dir, exist_ok=True)
     addr_file = os.path.join(run_dir, "addr.json")
     if os.path.exists(addr_file):
@@ -328,7 +330,7 @@ def spawn_http_manager(run_dir: str, workers: int, *, spares: int = 0,
          "--port", "0", "--addr-file", addr_file,
          "--state", os.path.join(run_dir, "state.json"),
          "--idle-timeout", str(idle_timeout_s)],
-        env={**os.environ,
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
              "PYTHONPATH": os.pathsep.join(
                  p for p in [os.environ.get("PYTHONPATH"), src_root]
                  if p)})

@@ -476,13 +476,15 @@ def spawn_file_manager(root: str, workers: int,
                        spares: int = 0) -> subprocess.Popen:
     """Start the file job manager as a separate process (the RPC actually
     crosses a process boundary).  The idle timeout is a safety net so an
-    orphaned server never outlives its job by much."""
+    orphaned server never outlives its job by much.  The server does no
+    device work, so it is pinned to the CPU platform: the trainer process
+    owns the accelerator."""
     return subprocess.Popen(
         [sys.executable, "-c",
          "from repro.cluster.rpc import main; main()", "--dir", root,
          "--workers", str(workers), "--idle-timeout",
          str(idle_timeout_s), "--spares", str(spares)],
-        env={**os.environ,
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
              "PYTHONPATH": os.pathsep.join(
                  p for p in [os.environ.get("PYTHONPATH"),
                              os.path.dirname(os.path.dirname(

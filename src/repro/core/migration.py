@@ -70,17 +70,60 @@ def build_plan(old_lps: Sequence[int], new_lps: Sequence[int],
     return MigrationPlan(src_stage, src_slot, valid, moved)
 
 
-def apply_plan(tree: Any, plan: MigrationPlan) -> Any:
-    """Gather [S, L_max, ...] arrays to the new layout.  Invalid (PAD)
-    destination slots keep zeros (their tags mark them inactive)."""
+def apply_plan(tree: Any, plan: MigrationPlan, sharding=None) -> Any:
+    """Gather [S_old, L_old, ...] arrays to the new [S, L_max, ...] layout.
+    Invalid (PAD) destination slots hold zeros (their tags mark them
+    inactive).
+
+    A leaf spread over several devices is rebuilt on ``sharding`` (default:
+    its own) by ``_gather_onto``, device by device; ``sharding`` may name
+    another device set (a live shrink or grow).  An eager gather over the
+    sharded stage axis would replicate whole leaves on every device, which
+    full-width state does not fit."""
     ss, sl, valid = plan.as_jnp()
 
     def gather(a):
+        spread = (isinstance(a, jax.Array)
+                  and not isinstance(a, jax.core.Tracer)
+                  and len(a.sharding.device_set) > 1)
+        if sharding is not None or spread:
+            return _gather_onto(a, plan, a.sharding if sharding is None
+                                else sharding)
         out = a[ss, sl]                      # [S, L_max, ...]
         mask = valid.reshape(valid.shape + (1,) * (out.ndim - 2))
         return jnp.where(mask, out, jnp.zeros_like(out))
 
     return jax.tree.map(gather, tree)
+
+
+def _gather_onto(a: jax.Array, plan: MigrationPlan, sharding) -> jax.Array:
+    """One leaf of ``apply_plan`` built on ``sharding`` without the host:
+    each destination device receives device-to-device copies of the
+    source-stage blocks its own stages draw from, then gathers its rows
+    locally, so no device holds another stage's blocks."""
+    ss, sl, valid = plan.src_stage, plan.src_slot, plan.valid
+    blocks = {}                      # source stage -> [1, L_old, ...] buffer
+    for sh in a.addressable_shards:
+        start, stop, _ = sh.index[0].indices(a.shape[0])
+        for s in range(start, stop):
+            if s not in blocks:
+                blocks[s] = (sh.data if stop - start == 1
+                             else sh.data[s - start:s - start + 1])
+    shape = ss.shape + a.shape[2:]
+    bufs = []
+    for dev, idx in sharding.addressable_devices_indices_map(shape).items():
+        lo, hi, _ = idx[0].indices(shape[0])
+        rows = []
+        for d in range(lo, hi):
+            need = sorted(set(ss[d][valid[d]].tolist())) or [0]
+            local = jnp.concatenate(
+                [jax.device_put(blocks[s], dev) for s in need])
+            pos = np.searchsorted(need, ss[d]).clip(0, len(need) - 1)
+            got = local[pos, sl[d]]                      # [L_max, ...]
+            mask = valid[d].reshape((-1,) + (1,) * (got.ndim - 1))
+            rows.append(jnp.where(mask, got, jnp.zeros_like(got))[None])
+        bufs.append(rows[0] if len(rows) == 1 else jnp.concatenate(rows))
+    return jax.make_array_from_single_device_arrays(shape, sharding, bufs)
 
 
 def _apply_plan_to_opt(opt_state: Any, plan: MigrationPlan) -> Any:

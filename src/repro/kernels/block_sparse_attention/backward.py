@@ -26,29 +26,31 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.block_sparse_attention.block_sparse_attention import (
-    NEG_INF, tile_active, tile_scores)
+    NEG_INF, mask_index, tile_active, tile_scores)
 
 
 def _tile_p_ds(q, k, v, do, lse, delta, *, qi, ki, sm_scale, causal,
                block_q, block_k, kv_len, sk_pad):
-    """Shared per-tile recompute: returns (p, ds) [bq, bk] in float32."""
+    """Shared per-tile recompute: returns (p, ds) [bq, bk] in float32.
+    ``lse`` and ``delta`` are [bq, 1] columns."""
     s = tile_scores(q, k, qi, ki, sm_scale=sm_scale, causal=causal,
                     block_q=block_q, block_k=block_k, kv_len=kv_len,
                     sk_pad=sk_pad)                          # [bq, bk]
-    p = jnp.exp(s - lse[:, None])
+    p = jnp.exp(s - lse)
     # masked entries: s=NEG_INF ⇒ p→0 when lse is finite; fully-masked rows
     # have lse≈NEG_INF (sentinel) which would make p spuriously 1 — zero them
-    p = jnp.where(lse[:, None] <= NEG_INF / 4, 0.0, p)
+    p = jnp.where(lse <= NEG_INF / 4, 0.0, p)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)                 # [bq, bk]
-    ds = p * (dp - delta[:, None]) * sm_scale
+    ds = p * (dp - delta) * sm_scale
     return p, ds
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, acc_ref, *, nkb: int, sm_scale: float, causal: bool,
-               block_q: int, block_k: int, kv_len: int):
+def _dq_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               dq_ref, acc_ref, *, nqb: int, nkb: int, sm_scale: float,
+               causal: bool, block_q: int, block_k: int, kv_len: int):
+    b = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     sk_pad = nkb * block_k
@@ -57,7 +59,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    active = tile_active(mask_ref[0, 0, 0], qi, ki, causal=causal,
+    active = tile_active(mask_ref[mask_index(b, qi, ki, nqb=nqb, nkb=nkb)],
+                         qi, ki, causal=causal,
                          block_q=block_q, block_k=block_k, kv_len=kv_len,
                          sk_pad=sk_pad)
 
@@ -78,10 +81,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
+def _dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *, nqb: int, nkb: int,
                 sm_scale: float, causal: bool, block_q: int, block_k: int,
                 kv_len: int):
+    b = pl.program_id(0)
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     sk_pad = nkb * block_k
@@ -91,7 +95,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    active = tile_active(mask_ref[0, 0, 0], qi, ki, causal=causal,
+    active = tile_active(mask_ref[mask_index(b, qi, ki, nqb=nqb, nkb=nkb)],
+                         qi, ki, causal=causal,
                          block_q=block_q, block_k=block_k, kv_len=kv_len,
                          sk_pad=sk_pad)
 
@@ -126,7 +131,8 @@ def block_sparse_attention_bwd_p(q, k, v, block_mask, dout, lse, delta, *,
     """Flash backward over pre-padded flat inputs.
 
     q, dout: [BH, sq, d]; k, v: [BH, sk, d]; block_mask: [BH, nqb, nkb];
-    lse, delta: [BH, sq] float32.  Returns (dq, dk, dv) in the input dtypes.
+    lse, delta: [BH, sq, 1] float32.  Returns (dq, dk, dv) in the input
+    dtypes.
     """
     BH, sq, d = q.shape
     sk = k.shape[1]
@@ -137,52 +143,48 @@ def block_sparse_attention_bwd_p(q, k, v, block_mask, dout, lse, delta, *,
         sm_scale = 1.0 / (d ** 0.5)
     if kv_len is None:
         kv_len = sk
+    flat_mask = block_mask.reshape(-1)
+    statics = dict(nqb=nqb, nkb=nkb, sm_scale=sm_scale, causal=causal,
+                   block_q=block_q, block_k=block_k, kv_len=kv_len)
 
-    q_spec_q = pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0))
-    k_spec_q = pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0))
-    row_spec_q = pl.BlockSpec((1, block_q), lambda b, qi, ki: (b, qi))
+    q_spec_q = pl.BlockSpec((1, block_q, d), lambda b, qi, ki, m: (b, qi, 0))
+    k_spec_q = pl.BlockSpec((1, block_k, d), lambda b, qi, ki, m: (b, ki, 0))
+    row_spec_q = pl.BlockSpec((1, block_q, 1),
+                              lambda b, qi, ki, m: (b, qi, 0))
     dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, nkb=nkb, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, kv_len=kv_len),
-        grid=(BH, nqb, nkb),
-        in_specs=[
-            q_spec_q, k_spec_q, k_spec_q,
-            pl.BlockSpec((1, 1, 1), lambda b, qi, ki: (b, qi, ki)),
-            q_spec_q, row_spec_q, row_spec_q,
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
+        functools.partial(_dq_kernel, **statics),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(BH, nqb, nkb),
+            in_specs=[q_spec_q, k_spec_q, k_spec_q, q_spec_q, row_spec_q,
+                      row_spec_q],
+            out_specs=q_spec_q,
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((BH, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, block_mask, dout, lse, delta)
+    )(flat_mask, q, k, v, dout, lse, delta)
 
     # kv sweep: grid order (BH, kv_blocks, q_blocks), q innermost
-    q_spec_k = pl.BlockSpec((1, block_q, d), lambda b, ki, qi: (b, qi, 0))
-    k_spec_k = pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0))
-    row_spec_k = pl.BlockSpec((1, block_q), lambda b, ki, qi: (b, qi))
+    q_spec_k = pl.BlockSpec((1, block_q, d), lambda b, ki, qi, m: (b, qi, 0))
+    k_spec_k = pl.BlockSpec((1, block_k, d), lambda b, ki, qi, m: (b, ki, 0))
+    row_spec_k = pl.BlockSpec((1, block_q, 1),
+                              lambda b, ki, qi, m: (b, qi, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, nqb=nqb, nkb=nkb, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, kv_len=kv_len),
-        grid=(BH, nkb, nqb),
-        in_specs=[
-            q_spec_k, k_spec_k, k_spec_k,
-            pl.BlockSpec((1, 1, 1), lambda b, ki, qi: (b, qi, ki)),
-            q_spec_k, row_spec_k, row_spec_k,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-        ],
+        functools.partial(_dkv_kernel, **statics),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(BH, nkb, nqb),
+            in_specs=[q_spec_k, k_spec_k, k_spec_k, q_spec_k, row_spec_k,
+                      row_spec_k],
+            out_specs=[k_spec_k, k_spec_k],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((BH, sk, d), k.dtype),
             jax.ShapeDtypeStruct((BH, sk, d), v.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
         interpret=interpret,
-    )(q, k, v, block_mask, dout, lse, delta)
+    )(flat_mask, q, k, v, dout, lse, delta)
     return dq, dk, dv

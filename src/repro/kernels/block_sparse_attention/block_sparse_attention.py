@@ -7,7 +7,10 @@ compute is issued — on TPU the saved time is the tile's matmul+softmax).
 
 Tiling: grid = (batch·heads, q_blocks, kv_blocks), kv innermost so the
 online-softmax accumulator lives in VMEM scratch across the kv sweep.
-Block shapes default to (128, 128) — MXU-aligned.
+Block shapes default to (128, 128) — MXU-aligned.  The block mask rides as
+scalar prefetch (a flat int32 vector in SMEM, like paged_attention's page
+table), and per-row statistics are ``[rows, 1]`` columns, so every VMEM
+block has a shape the TPU lowering accepts.
 
 The forward emits the per-row log-sum-exp alongside the output so the
 backward kernels (backward.py) can recompute probabilities tile-by-tile
@@ -65,9 +68,15 @@ def tile_scores(q, k, qi, ki, *, sm_scale: float, causal: bool,
     return s
 
 
-def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref, m_ref,
-            l_ref, *, nkb: int, sm_scale: float, causal: bool, block_q: int,
-            block_k: int, kv_len: int):
+def mask_index(b, qi, ki, *, nqb: int, nkb: int):
+    """Position of tile (b, qi, ki) in the flat ``[BH * nqb * nkb]`` mask."""
+    return (b * nqb + qi) * nkb + ki
+
+
+def _kernel(mask_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
+            l_ref, *, nqb: int, nkb: int, sm_scale: float, causal: bool,
+            block_q: int, block_k: int, kv_len: int):
+    b = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     sk_pad = nkb * block_k
@@ -78,7 +87,8 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref, m_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    active = tile_active(mask_ref[0, 0, 0], qi, ki, causal=causal,
+    active = tile_active(mask_ref[mask_index(b, qi, ki, nqb=nqb, nkb=nkb)],
+                         qi, ki, causal=causal,
                          block_q=block_q, block_k=block_k, kv_len=kv_len,
                          sk_pad=sk_pad)
 
@@ -90,16 +100,16 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref, m_ref,
         s = tile_scores(q, k, qi, ki, sm_scale=sm_scale, causal=causal,
                         block_q=block_q, block_k=block_k, kv_len=kv_len,
                         sk_pad=sk_pad)             # [bq, bk]
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                        # [bq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         # a row with NO live entry so far has m_new == NEG_INF, making
         # p = exp(0) = 1 for its all-masked columns (e.g. block_q > block_k
         # tiles entirely above the diagonal band) — zero it so l stays 0
-        p = jnp.where(m_new[:, None] <= NEG_INF / 2, 0.0, p)
+        p = jnp.where(m_new <= NEG_INF / 2, 0.0, p)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * corr
                         + jax.lax.dot_general(
                             p, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
@@ -108,9 +118,9 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref, m_ref,
     @pl.when(ki == nkb - 1)
     def _finish():
         l = l_ref[...]
-        out = acc_ref[...] / jnp.maximum(l, 1e-30)[:, None]
+        out = acc_ref[...] / jnp.maximum(l, 1e-30)
         # fully-masked rows (l == 0) emit zeros
-        out = jnp.where((l > 0)[:, None], out, 0.0)
+        out = jnp.where(l > 0, out, 0.0)
         o_ref[0] = out.astype(o_ref.dtype)
         # lse of fully-masked rows stays ~NEG_INF: the backward zeroes their
         # probabilities off that sentinel (zero, not NaN, gradients)
@@ -126,7 +136,7 @@ def block_sparse_attention_p(q, k, v, block_mask, *, causal: bool = True,
 
     Shapes must be pre-padded to block multiples (ops.py handles that);
     ``kv_len`` is the unpadded key length (defaults to sk = no padding).
-    Returns (out [BH, sq, d], lse [BH, sq] float32)."""
+    Returns (out [BH, sq, d], lse [BH, sq, 1] float32)."""
     BH, sq, d = q.shape
     sk = k.shape[1]
     assert sq % block_q == 0 and sk % block_k == 0, (sq, sk)
@@ -138,29 +148,31 @@ def block_sparse_attention_p(q, k, v, block_mask, *, causal: bool = True,
         kv_len = sk
 
     kernel = functools.partial(
-        _kernel, nkb=nkb, sm_scale=sm_scale, causal=causal,
+        _kernel, nqb=nqb, nkb=nkb, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, kv_len=kv_len)
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(BH, nqb, nkb),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, qi, ki: (b, qi, ki)),
+            pl.BlockSpec((1, block_q, d), lambda b, qi, ki, m: (b, qi, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, qi, ki, m: (b, ki, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b, qi, ki, m: (b, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda b, qi, ki: (b, qi)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((BH, sq), jnp.float32),
+            pl.BlockSpec((1, block_q, d), lambda b, qi, ki, m: (b, qi, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, qi, ki, m: (b, qi, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((BH, sq, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, block_mask)
+    )(block_mask.reshape(-1), q, k, v)

@@ -2,7 +2,8 @@
 padding to block multiples) around the Pallas block-sparse attention kernel,
 plus the custom-VJP that routes the backward through the Pallas flash
 backward kernels (backward.py) — masked tiles skip work in both directions.
-``interpret=True`` executes the kernel bodies on CPU for validation."""
+``interpret=True`` executes the kernel bodies on the CPU for validation;
+callers pick it with ``repro.kernels.use_interpret()``."""
 from __future__ import annotations
 
 import functools
@@ -41,7 +42,7 @@ def _bsa_flat_fwd(q, k, v, block_mask, causal, block_q, block_k, kv_len,
 def _bsa_flat_bwd(causal, block_q, block_k, kv_len, interpret, res, dout):
     q, k, v, block_mask, out, lse = res
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                                 # [BH, sq]
+                    axis=-1, keepdims=True)                  # [BH, sq, 1]
     dq, dk, dv = block_sparse_attention_bwd_p(
         q, k, v, block_mask.astype(jnp.int32), dout, lse, delta,
         causal=causal, block_q=block_q, block_k=block_k, kv_len=kv_len,

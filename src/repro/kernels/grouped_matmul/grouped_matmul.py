@@ -13,8 +13,9 @@ pays full ``cap`` rows per expert unconditionally.
 Group g uses weight ``w[g % E]``: groups are batch-major (g = bi * E + e)
 so every batch row's expert-e tokens hit the same expert weights.
 
-The counts ride in as a 1-D array with a ``(1,)`` BlockSpec (same idiom as
-pruned_matmul's block mask) — proven on both interpret and compiled paths.
+The counts ride as scalar prefetch (an int32 vector in SMEM, as in
+paged_attention and pruned_matmul), so the gating scalar is read without a
+VMEM block.
 """
 from __future__ import annotations
 
@@ -30,8 +31,9 @@ def _scratch(shape):
     return pltpu.VMEM(shape, jnp.float32)
 
 
-def _gm_kernel(x_ref, w_ref, c_ref, o_ref, acc_ref, *, nkb, bm):
+def _gm_kernel(c_ref, x_ref, w_ref, o_ref, acc_ref, *, nkb, bm):
     """One (group, row-tile, n-tile, k-tile) cell; k innermost accumulates."""
+    g = pl.program_id(0)
     i = pl.program_id(1)
     ki = pl.program_id(3)
 
@@ -41,7 +43,7 @@ def _gm_kernel(x_ref, w_ref, c_ref, o_ref, acc_ref, *, nkb, bm):
 
     # live-row tile test: rows are packed front-of-group, so a tile whose
     # first row index reaches the count holds no live rows at all
-    @pl.when(i * bm < c_ref[0])
+    @pl.when(i * bm < c_ref[g])
     def _compute():
         acc_ref[...] += jax.lax.dot_general(
             x_ref[...], w_ref[0], (((1,), (0,)), ((), ())),
@@ -55,40 +57,46 @@ def _gm_kernel(x_ref, w_ref, c_ref, o_ref, acc_ref, *, nkb, bm):
 def grouped_matmul_p(x, w, counts, *, gpb: int, bm: int, bn: int, bk: int,
                      interpret: bool = False):
     """x: [G*cap, K] row-sorted groups (cap = gpb*bm rows each, dead rows
-    zero), w: [E, K, N] with G % E == 0, counts: [G].  Returns [G*cap, N].
-    K/N must be block multiples (pad outside)."""
+    zero), w: [E, K, N] with G % E == 0, counts: [G] (any numeric dtype;
+    read as int32).  Returns [G*cap, N].  K/N must be block multiples (pad
+    outside)."""
     M, K = x.shape
     E, _, N = w.shape
     G = M // (gpb * bm)
     assert M == G * gpb * bm and G % E == 0, (M, G, gpb, bm, E)
     assert K % bk == 0 and N % bn == 0, (K, bk, N, bn)
     nkb = K // bk
-    grid = (G, gpb, N // bn, nkb)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(G, gpb, N // bn, nkb),
+        in_specs=[
+            pl.BlockSpec((bm, bk), lambda g, i, j, k, c: (g * gpb + i, k)),
+            pl.BlockSpec((1, bk, bn), lambda g, i, j, k, c: (g % E, k, j)),
+        ],
+        out_specs=pl.BlockSpec((bm, bn),
+                               lambda g, i, j, k, c: (g * gpb + i, j)),
+        scratch_shapes=[_scratch((bm, bn))],
+    )
     return pl.pallas_call(
         functools.partial(_gm_kernel, nkb=nkb, bm=bm),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda g, i, j, k: (g * gpb + i, k)),
-            pl.BlockSpec((1, bk, bn), lambda g, i, j, k: (g % E, k, j)),
-            pl.BlockSpec((1,), lambda g, i, j, k: (g,)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda g, i, j, k: (g * gpb + i, j)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        scratch_shapes=[_scratch((bm, bn))],
         interpret=interpret,
-    )(x, w, counts)
+    )(counts.astype(jnp.int32), x, w)
 
 
-def _gm_dw_kernel(x_ref, g_ref, c_ref, o_ref, acc_ref, *, nrb, bm, gpb):
+def _gm_dw_kernel(c_ref, x_ref, g_ref, o_ref, acc_ref, *, nrb, bm, gpb,
+                  num_experts):
     """dw[e] = sum over batch groups of x_{b,e}^T @ g_{b,e}; the row-chunk
     axis r (innermost) walks every (batch, row-tile) pair of expert e."""
+    e = pl.program_id(0)
     r = pl.program_id(3)
 
     @pl.when(r == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when((r % gpb) * bm < c_ref[0])
+    @pl.when((r % gpb) * bm < c_ref[(r // gpb) * num_experts + e])
     def _compute():
         acc_ref[...] += jax.lax.dot_general(
             x_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
@@ -111,17 +119,21 @@ def grouped_matmul_dw_p(x, g, counts, *, num_experts: int, gpb: int,
     assert G % E == 0, (G, E)
     nrb = (G // E) * gpb
     row = lambda e, r: ((r // gpb) * E + e) * gpb + (r % gpb)
-    grid = (E, K // bk, N // bn, nrb)
-    return pl.pallas_call(
-        functools.partial(_gm_dw_kernel, nrb=nrb, bm=bm, gpb=gpb),
-        grid=grid,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(E, K // bk, N // bn, nrb),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda e, kk, j, r: (row(e, r), kk)),
-            pl.BlockSpec((bm, bn), lambda e, kk, j, r: (row(e, r), j)),
-            pl.BlockSpec((1,), lambda e, kk, j, r: ((r // gpb) * E + e,)),
+            pl.BlockSpec((bm, bk), lambda e, kk, j, r, c: (row(e, r), kk)),
+            pl.BlockSpec((bm, bn), lambda e, kk, j, r, c: (row(e, r), j)),
         ],
-        out_specs=pl.BlockSpec((1, bk, bn), lambda e, kk, j, r: (e, kk, j)),
-        out_shape=jax.ShapeDtypeStruct((E, K, N), jnp.float32),
+        out_specs=pl.BlockSpec((1, bk, bn),
+                               lambda e, kk, j, r, c: (e, kk, j)),
         scratch_shapes=[_scratch((bk, bn))],
+    )
+    return pl.pallas_call(
+        functools.partial(_gm_dw_kernel, nrb=nrb, bm=bm, gpb=gpb,
+                          num_experts=E),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((E, K, N), jnp.float32),
         interpret=interpret,
-    )(x, g, counts)
+    )(counts.astype(jnp.int32), x, g)

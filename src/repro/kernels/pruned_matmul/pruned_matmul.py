@@ -12,6 +12,8 @@ Two mask positions:
     used for the FFN up-projection x@W1;
   * mask over K (reduction blocks): pruned rows skip accumulation — used for
     the down-projection h@W2 (h's pruned columns are dead anyway).
+
+The mask rides as scalar prefetch (SMEM), as in paged_attention.
 """
 from __future__ import annotations
 
@@ -23,14 +25,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel_mask_n(x_ref, w_ref, mask_ref, o_ref, acc_ref, *, nkb: int):
+def _kernel(mask_ref, x_ref, w_ref, o_ref, acc_ref, *, nkb: int,
+            mask_axis: str):
+    """One (row-tile, col-tile, k-tile) cell; k innermost accumulates.  The
+    flat block mask sits in SMEM (scalar prefetch) and is read at the
+    column tile (mask over N) or the reduction tile (mask over K)."""
+    j = pl.program_id(1)
     ki = pl.program_id(2)
+    live = mask_ref[j if mask_axis == "n" else ki] > 0
 
     @pl.when(ki == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(mask_ref[0] > 0)
+    @pl.when(live)
     def _compute():
         acc_ref[...] += jax.lax.dot_general(
             x_ref[...].astype(jnp.float32), w_ref[...].astype(jnp.float32),
@@ -38,26 +46,10 @@ def _kernel_mask_n(x_ref, w_ref, mask_ref, o_ref, acc_ref, *, nkb: int):
 
     @pl.when(ki == nkb - 1)
     def _finish():
-        o_ref[...] = jnp.where(mask_ref[0] > 0,
-                               acc_ref[...], 0.0).astype(o_ref.dtype)
-
-
-def _kernel_mask_k(x_ref, w_ref, mask_ref, o_ref, acc_ref, *, nkb: int):
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(mask_ref[0] > 0)
-    def _compute():
-        acc_ref[...] += jax.lax.dot_general(
-            x_ref[...].astype(jnp.float32), w_ref[...].astype(jnp.float32),
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-
-    @pl.when(ki == nkb - 1)
-    def _finish():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        acc = acc_ref[...]
+        if mask_axis == "n":
+            acc = jnp.where(live, acc, 0.0)
+        o_ref[...] = acc.astype(o_ref.dtype)
 
 
 def pruned_matmul_p(x, w, block_mask, *, mask_axis: str = "n",
@@ -72,24 +64,21 @@ def pruned_matmul_p(x, w, block_mask, *, mask_axis: str = "n",
     _, N = w.shape
     assert M % bm == 0 and K % bk == 0 and N % bn == 0, (M, K, N)
     nkb = K // bk
-    if mask_axis == "n":
-        assert block_mask.shape == (N // bn,), block_mask.shape
-        kernel = functools.partial(_kernel_mask_n, nkb=nkb)
-        mask_spec = pl.BlockSpec((1,), lambda i, j, k_: (j,))
-    else:
-        assert block_mask.shape == (nkb,), block_mask.shape
-        kernel = functools.partial(_kernel_mask_k, nkb=nkb)
-        mask_spec = pl.BlockSpec((1,), lambda i, j, k_: (k_,))
-    return pl.pallas_call(
-        kernel,
+    n_mask = N // bn if mask_axis == "n" else nkb
+    assert block_mask.shape == (n_mask,), block_mask.shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(M // bm, N // bn, nkb),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k_: (i, k_)),
-            pl.BlockSpec((bk, bn), lambda i, j, k_: (k_, j)),
-            mask_spec,
+            pl.BlockSpec((bm, bk), lambda i, j, k_, m: (i, k_)),
+            pl.BlockSpec((bk, bn), lambda i, j, k_, m: (k_, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k_: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k_, m: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, nkb=nkb, mask_axis=mask_axis),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=interpret,
-    )(x, w, block_mask.astype(jnp.int32))
+    )(block_mask.astype(jnp.int32), x, w)

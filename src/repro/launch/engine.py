@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from repro.cluster.rpc import (InProcessJobManager, JobManagerClient,
                                JobManagerUnavailable)
 from repro.configs.base import DistConfig, ModelConfig
+from repro.core.migration import apply_plan, build_plan
 from repro.dynamics.config import DynamicsConfig
 from repro.launch.mesh import make_submesh
 from repro.models import model as M
@@ -236,6 +237,11 @@ class ElasticEngine:
             self.pool.unsubscribe(self._pool_hook)
 
     # -- worlds ------------------------------------------------------------
+    @property
+    def worlds(self) -> List[EngineWorld]:
+        """Every execution world built so far, oldest first."""
+        return list(self._worlds.values())
+
     def dcfg_for(self, stages: int) -> DistConfig:
         return dataclasses.replace(self.base_dcfg, num_stages=stages)
 
@@ -350,16 +356,20 @@ class ElasticEngine:
 
     # -- placement ---------------------------------------------------------
     def _place(self, world: EngineWorld, params, opt_state, dyn, assignment,
-               cache=None):
+               cache=None, plan=None):
         """device_put onto the world's submesh with the pipeline's layout:
         stage-keyed leaves sharded over ``model`` (leading stage dim),
         everything else replicated — matches the shard_map in_specs, so the
-        jitted step needs no input reshard."""
+        jitted step needs no input reshard.  With a migration ``plan`` (a
+        live resize) the stage-keyed leaves are re-split onto the world's
+        devices instead (``migration.apply_plan``)."""
         from jax.sharding import NamedSharding, PartitionSpec as P
         stage_sh = NamedSharding(world.mesh, P("model"))
         repl_sh = NamedSharding(world.mesh, P())
-        put_st = lambda t: jax.tree.map(
+        put_as_is = lambda t: jax.tree.map(
             lambda a: jax.device_put(a, stage_sh), t)
+        put_st = (put_as_is if plan is None
+                  else lambda t: apply_plan(t, plan, stage_sh))
         put_rp = lambda t: jax.tree.map(
             lambda a: jax.device_put(a, repl_sh), t)
         params = {k: (put_st(v) if k == "stages" else put_rp(v))
@@ -373,7 +383,8 @@ class ElasticEngine:
 
         opt_state = walk_opt(opt_state) if opt_state is not None else None
         cache = put_st(cache) if cache is not None else None
-        return params, opt_state, put_st(dyn), put_st(assignment), cache
+        return (params, opt_state, put_st(dyn), put_as_is(assignment),
+                cache)
 
     # -- lifecycle ---------------------------------------------------------
     def init_state(self, rng: jax.Array, *, with_opt: bool = True,
@@ -388,9 +399,9 @@ class ElasticEngine:
         matching workers first (``bind_workers``)."""
         stages = stages if stages is not None else self.base_dcfg.num_stages
         world = self.world(stages)
-        params = M.init_params(rng, self.cfg, world.dcfg)
         lps = (list(lps) if lps is not None
                else M.uniform_boundaries(self.cfg.total_blocks(), stages))
+        params = M.init_params(rng, self.cfg, world.dcfg, lps)
         assignment = M.make_assignment(self.cfg, world.dcfg, lps)
         dyn = M.init_dyn(self.cfg, world.dcfg, self.dyncfg)
         opt_state = world.init_opt(params) if with_opt else None
@@ -419,6 +430,13 @@ class ElasticEngine:
         w = self.world(state.stages)
         self.last_step_compiled = not w.stepped
         w.stepped = True
+        # leaves rebuilt by a rebalance, a pruning event or a host-side edit
+        # carry shardings the compiled step has not seen, and would make it
+        # recompile; placing them onto the world's layout costs nothing for
+        # leaves already there
+        (state.params, state.opt_state, state.dyn, state.assignment,
+         _) = self._place(w, state.params, state.opt_state, state.dyn,
+                          state.assignment)
         with w.mesh:
             params, opt_state, loss, stats, gnorm = w.step(
                 state.params, state.opt_state, state.assignment, state.dyn,
@@ -629,22 +647,17 @@ class ElasticEngine:
         dims are gathered exactly like params), so in-flight KV state
         survives the resize bit-identically.  Falls back to a uniform split
         when ``new_lps`` violates the target world's slot capacity."""
-        from repro.checkpoint.elastic import (_resplit_stage_tree,
-                                              elastic_restore)
         world = self.world(new_stages, workers)
-        if new_lps is not None and (
-                len(new_lps) != new_stages
-                or max(new_lps) > world.dcfg.slots_for(self.cfg)):
-            new_lps = None
-        params, opt_state, dyn, assignment, lps = elastic_restore(
-            self.cfg, self.dcfg_for(state.stages), world.dcfg,
-            state.params, state.opt_state, state.dyn, state.lps, new_lps)
-        cache = state.cache
-        if cache is not None:
-            cache = _resplit_stage_tree(cache, state.lps, lps,
-                                        world.dcfg.slots_for(self.cfg))
+        slots = world.dcfg.slots_for(self.cfg)
+        if (new_lps is None or len(new_lps) != new_stages
+                or max(new_lps) > slots):
+            new_lps = M.uniform_boundaries(self.cfg.total_blocks(),
+                                           new_stages)
+        lps = list(new_lps)
         params, opt_state, dyn, assignment, cache = self._place(
-            world, params, opt_state, dyn, assignment, cache)
+            world, state.params, state.opt_state, state.dyn,
+            M.make_assignment(self.cfg, world.dcfg, lps), state.cache,
+            plan=build_plan(state.lps, lps, slots))
         self.epoch += 1
         return EngineState(params, opt_state, dyn, assignment, lps,
                            new_stages, cache)

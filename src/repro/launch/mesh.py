@@ -11,14 +11,9 @@ import jax
 
 
 def _auto_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types, tolerant of jax versions where
-    ``axis_types`` (jax.sharding.AxisType, >= 0.5) does not exist yet —
-    Auto is the implicit default there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis left to XLA's SPMD partitioner."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -39,6 +39,7 @@ from repro.api.cli import (SERVE_ALIASES, SERVE_CLI_DEFAULTS,
 from repro.api.session import Session
 from repro.api.specs import (ClusterSpec, ControllerSpec, DynamicsSpec,
                              ModelSpec, ParallelSpec, RunSpec, ServeSpec)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run_serving(arch: str, *, stages: int = 4, micro: int = 2,
@@ -203,6 +204,7 @@ def main(argv=None):
     add_alias_flags(ap, SERVE_ALIASES)
     add_spec_flags(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     spec = build_spec(args, SERVE_ALIASES, cli_defaults=SERVE_CLI_DEFAULTS)
     if maybe_dump(args, spec):
         return

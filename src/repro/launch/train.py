@@ -35,6 +35,7 @@ from repro.api.cli import (TRAIN_ALIASES, TRAIN_CLI_DEFAULTS,
 from repro.api.session import Session
 from repro.api.specs import (ClusterSpec, ControllerSpec, DynamicsSpec,
                              ModelSpec, ParallelSpec, RepackSpec, RunSpec)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.engine import ElasticEngine, make_train_step  # noqa: F401
 # make_train_step / ElasticEngine are re-exported for back-compat
 # (tests/examples import them from here); engine.py owns step assembly.
@@ -120,6 +121,7 @@ def main(argv=None):
     add_alias_flags(ap, TRAIN_ALIASES)
     add_spec_flags(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.resume:
         sess = Session.resume(args.resume)
     else:

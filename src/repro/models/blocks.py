@@ -24,6 +24,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
 from repro.configs.base import (
     BLOCK_DEC, BLOCK_DENSE, BLOCK_ENC, BLOCK_HYBRID_ATTN, BLOCK_MAMBA,
     BLOCK_MLSTM, BLOCK_MOE, BLOCK_PAD, BLOCK_SLSTM, ModelConfig,
@@ -378,8 +379,8 @@ def _attn_fwd(x, wq, wk, wv, wo, *, cfg, mode, cache, pos,
         clen = jnp.minimum(pvec + 1, cap)
         if kernel_impl == "pallas":
             from repro.kernels.paged_attention import paged_attention
-            interpret = jax.default_backend() != "tpu"
-            out = paged_attention(q, kp, vp, pt, clen, interpret=interpret)
+            out = paged_attention(q, kp, vp, pt, clen,
+                                  interpret=kernels.use_interpret())
         else:
             from repro.kernels.paged_attention import paged_attention_ref
             out = paged_attention_ref(q, kp, vp, pt, clen)
@@ -497,7 +498,7 @@ def moe_ffn(p, x, cfg: ModelConfig, *, kernel_impl: str = "scan",
 
     if kernel_impl == "pallas":
         from repro.kernels.grouped_matmul import grouped_matmul
-        interpret = jax.default_backend() != "tpu"
+        interpret = kernels.use_interpret()
         if expert_map is None:
             pm = jnp.arange(E, dtype=jnp.int32)
         else:

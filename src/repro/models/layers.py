@@ -13,6 +13,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
+
 NEG_INF = -1e30
 KERNEL_IMPLS = ("reference", "scan", "pallas")
 
@@ -34,11 +36,8 @@ def pin_batch(x: jax.Array) -> jax.Array:
     fallback); pinning the batch dim of block-internal tensors keeps the
     per-tick working set 1/dp-sized.  No-op outside a mesh context or when
     the batch dim is not divisible."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:   # noqa: BLE001
-        return x
-    if am is None or not getattr(am, "axis_names", None):
+    am = jax.sharding.get_abstract_mesh()
+    if not am.axis_names:
         return x
     daxes = tuple(a for a in am.axis_names
                   if a != "model" and am.shape[a] > 1)
@@ -107,7 +106,7 @@ def swiglu(x: jax.Array, wi: jax.Array, wg: jax.Array, wo: jax.Array,
                 ff_mask.shape, d_ff)
             bmask, bf = ff_mask, d_ff // nb
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            interpret = kernels.use_interpret()
         return pin_batch(pruned_swiglu(x, wi, wg, wo, bmask, bf=bf,
                                        interpret=interpret))
     h = pin_batch(jax.nn.silu(x @ wg) * (x @ wi))
@@ -141,7 +140,7 @@ def gelu_mlp(x: jax.Array, w1: jax.Array, b1: jax.Array, w2: jax.Array,
             d_ff)
         bf = d_ff // nb
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            interpret = kernels.use_interpret()
         h = pruned_matmul(x, w1, bmask, mask_axis="n", bn=bf,
                           interpret=interpret) + b1
         h = jax.nn.gelu(h) * jnp.repeat(bmask, bf).astype(x.dtype)
@@ -259,7 +258,7 @@ def _pallas_attention(q, k, v, block_mask, causal, kv_block,
         bm = bm[:, :, qb][:, :, :, kb]
         bm = jnp.broadcast_to(bm, (b, hq, nqb, nkb)).astype(jnp.float32)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = kernels.use_interpret()
     return block_sparse_attention(q, k, v, bm, causal=causal, block_q=block,
                                   block_k=block, interpret=interpret)
 

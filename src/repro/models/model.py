@@ -109,13 +109,30 @@ def param_spec(cfg: ModelConfig, dcfg: DistConfig) -> Dict[str, Any]:
     return spec
 
 
-def init_params(rng: jax.Array, cfg: ModelConfig,
-                dcfg: DistConfig) -> Dict[str, Any]:
+def init_params(rng: jax.Array, cfg: ModelConfig, dcfg: DistConfig,
+                layers_per_stage: Optional[Sequence[int]] = None
+                ) -> Dict[str, Any]:
+    """Seeded random parameters laid out for ``layers_per_stage`` (uniform
+    by default).  A layer's weights come from its global index, not from
+    the slot it lands in, so every stage layout starts from the same model;
+    padding slots draw from keys of their own."""
+    import numpy as np
     dt = _dtype_of(dcfg)
     S, L_max = dcfg.num_stages, dcfg.slots_for(cfg)
+    L = cfg.total_blocks()
     k_emb, k_head, k_slots, k_shared = jax.random.split(rng, 4)
-    slot_keys = jax.random.split(k_slots, S * L_max).reshape(S, L_max, 2)
-    stages = jax.vmap(jax.vmap(lambda k: B.init_slot(k, cfg, dt)))(slot_keys)
+    k_layers, k_pad = jax.random.split(k_slots)
+    keys = jnp.concatenate([jax.random.split(k_layers, L),
+                            jax.random.split(k_pad, S * L_max)])
+    idx = L + np.arange(S * L_max).reshape(S, L_max)
+    lps = (uniform_boundaries(L, S) if layers_per_stage is None
+           else list(layers_per_stage))
+    first = 0
+    for s, n in enumerate(lps):
+        idx[s, :n] = np.arange(first, first + n)
+        first += n
+    stages = jax.vmap(jax.vmap(lambda k: B.init_slot(k, cfg, dt)))(
+        keys[idx])
     params = {
         "embed": jax.random.normal(k_emb, (cfg.vocab_size, cfg.d_model),
                                    jnp.float32) * 0.02,
@@ -417,6 +434,11 @@ def stage_forward(cfg: ModelConfig, dcfg: DistConfig, dyncfg: DynamicsConfig,
                 dyn_slot["frozen"], p, operand)
             return out_carry, cache_slot, stats, aux, jnp.float32(1.0)
         return run(carry)
+
+    if dcfg.remat == "block" and mode == "train":
+        # per-slot rematerialization: the backward keeps each slot's input
+        # carry and recomputes the block's internals from it
+        slot_fn = jax.checkpoint(slot_fn, prevent_cse=False)
 
     if dcfg.slot_exec == "bounded_loop" and not dcfg.unroll_slots:
         # data-dependent trip count: a lightly-loaded stage does less work

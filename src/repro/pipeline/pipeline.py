@@ -1,6 +1,8 @@
 """Pipeline-parallel runtime: GPipe-style microbatch schedule over the
 ``model`` mesh axis via jax.shard_map (manual) with ``data``/``pod`` axes left
 to XLA SPMD (auto) — FSDP/DP/vocab sharding ride on jit-level in_shardings.
+DP axes of size 1 are made manual too: a region with no auto axis is what
+Mosaic (Pallas TPU) kernels and host callbacks require.
 
 The forward schedule is differentiable; jax.grad generates the reverse
 pipeline (backward ppermutes run in the transposed direction), so 1F1B-like
@@ -30,20 +32,21 @@ from repro.models import model as M
 AUX_LOSS_COEF = 0.01
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, axis_names):
-    """jax.shard_map compat: on older jax (< jax.shard_map) fall back to
-    jax.experimental.shard_map, fully manual, with check_rep=False
-    (≙ check_vma=False).  Partial-auto (``auto=``) is deliberately NOT used
-    there: it lowers axis_index via PartitionId, which XLA-CPU SPMD rejects.
-    Axes unmentioned by the specs simply replicate — same math, DP/FSDP
-    sharding of the non-manual axes only applies on current jax."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+def _auto_axes(mesh) -> tuple:
+    """The DP axes XLA's SPMD partitioner splits (size > 1); every other
+    axis of the pipeline's shard_map is manual."""
+    return tuple(a for a in mesh.axis_names
+                 if a != "model" and mesh.shape[a] > 1)
+
+
+def _shard_map(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` manual over the pipeline axis and the size-1 DP
+    axes.  The compiled Pallas kernels cannot be partitioned by XLA, so on
+    the TPU they run only where ``_auto_axes(mesh)`` is empty."""
+    manual = set(mesh.axis_names) - set(_auto_axes(mesh))
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=manual,
+                         check_vma=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,24 +108,22 @@ def _make_pin(mesh, dcfg):
     XLA's auto propagation sometimes assigns conflicting shardings to the
     carry across while-loop iterations and falls back to full
     rematerialization (replication) — pinning dim 0 at every tick boundary
-    keeps the layout stable.  No-op when the batch dim is not divisible."""
+    keeps the layout stable.  No-op when the batch dim is not divisible or
+    no DP axis is left to the partitioner."""
     from jax.sharding import NamedSharding
-    daxes = tuple(a for a in mesh.axis_names if a != "model")
+    daxes = _auto_axes(mesh)
+    if not daxes or not dcfg.pin_carry_sharding:
+        return lambda tree: tree
     dp = 1
     for a in daxes:
         dp *= mesh.shape[a]
     spec_axes = daxes if len(daxes) > 1 else daxes[0]
 
     def pin(x):
-        if not dcfg.pin_carry_sharding:
-            return x
         if x.ndim >= 1 and x.shape[0] % dp == 0 and x.shape[0] >= dp:
             # the constraint must be built on the *context* (abstract) mesh:
-            # inside shard_map 'model' is Manual there, not Auto.  Older jax
-            # has no abstract-mesh tracking — the pin is a no-op there.
-            am = getattr(jax.sharding, "get_abstract_mesh", lambda: None)()
-            if am is None:
-                return x
+            # inside shard_map 'model' is Manual there, not Auto
+            am = jax.sharding.get_abstract_mesh()
             return jax.lax.with_sharding_constraint(
                 x, NamedSharding(am, P(spec_axes,
                                        *([None] * (x.ndim - 1)))))
@@ -316,7 +317,7 @@ def build_loss_fn(cfg: ModelConfig, dcfg: DistConfig, dyncfg: DynamicsConfig,
     )
     return _shard_map(
         pipe, mesh=mesh, in_specs=in_specs,
-        out_specs=(P(), P("model")), axis_names={"model"})
+        out_specs=(P(), P("model")))
 
 
 # ---------------------------------------------------------------------------
@@ -517,7 +518,7 @@ def build_decode_fn(cfg: ModelConfig, dcfg: DistConfig,
         P("model"), P("model"), P("model"), P(), P()) + (P(),) * n_extra
     return _shard_map(
         pipe, mesh=mesh, in_specs=in_specs,
-        out_specs=(P(), P(), P("model"), P()), axis_names={"model"})
+        out_specs=(P(), P(), P("model"), P()))
 
 
 # ---------------------------------------------------------------------------
@@ -628,4 +629,4 @@ def build_prefill_fn(cfg: ModelConfig, dcfg: DistConfig,
         P("model"), P("model"), P("model"), P())
     return _shard_map(
         pipe, mesh=mesh, in_specs=in_specs,
-        out_specs=(P(), P("model"), P()), axis_names={"model"})
+        out_specs=(P(), P("model"), P()))

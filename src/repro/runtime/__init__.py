@@ -1,9 +1,4 @@
-from repro.runtime.fault_tolerance import (HeartbeatMonitor, StragglerDetector,
-                                           WorkerPool)
-from repro.runtime.compression import (compress_topk, decompress_topk,
-                                       int8_quantize, int8_dequantize,
-                                       compressed_psum)
-
-__all__ = ["HeartbeatMonitor", "StragglerDetector", "WorkerPool",
-           "compress_topk", "decompress_topk", "int8_quantize",
-           "int8_dequantize", "compressed_psum"]
+"""Runtime services: ``fault_tolerance`` (worker pool, heartbeats,
+straggler detection) and ``compression`` (gradient codecs).  Import the
+submodules directly; the package imports nothing, so host-only processes
+that need ``fault_tolerance`` start without JAX."""

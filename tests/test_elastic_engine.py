@@ -89,6 +89,26 @@ def test_shrink_grow_roundtrip_bit_identical():
     assert int(o4["count"]) == int(opt["count"])
 
 
+@pytest.mark.parametrize("stages,slack,lps", [
+    (4, 2, None), (2, 0, None), (4, 2, [3, 1, 2, 2])])
+def test_init_params_do_not_depend_on_layout(stages, slack, lps):
+    """A layer's initial weights come from its global index: every stage
+    layout of one seed starts from the model a single stage holds."""
+    cfg = reduced_config(get_config("smollm-360m"), num_layers=8,
+                         d_model=64, d_ff=128)
+    one = M.init_params(jax.random.PRNGKey(0), cfg,
+                        DistConfig(num_stages=1, slot_slack=0))
+    dcfg = DistConfig(num_stages=stages, slot_slack=slack)
+    many = M.init_params(jax.random.PRNGKey(0), cfg, dcfg, lps)
+    lps = lps or M.uniform_boundaries(8, stages)
+    slots = [(s, l) for s in range(stages) for l in range(lps[s])]
+    for name in ("embed", "head", "final_norm"):
+        np.testing.assert_array_equal(one[name], many[name])
+    for field, a in one["stages"].items():
+        for g, (s, l) in enumerate(slots):
+            np.testing.assert_array_equal(a[0, g], many["stages"][field][s, l])
+
+
 def test_resplit_rejects_bad_splits():
     with pytest.raises(AssertionError):
         resplit_indices([2, 2], [3, 2], 4)       # layer count not conserved

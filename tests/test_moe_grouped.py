@@ -29,15 +29,6 @@ from repro.kernels.grouped_matmul import (grouped_matmul, grouped_matmul_ref,
 from repro.models import model as M
 from repro.models.blocks import moe_ffn
 
-# see tests/test_system.py: MoE grad through jax<0.5's experimental
-# shard_map transpose trips an upstream _SpecError; forward-only paths
-# (serving, eval_loss, resize) are fine on both.
-requires_modern_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="MoE grad through jax.experimental.shard_map (jax<0.5) hits an "
-           "upstream _SpecError; needs jax.shard_map")
-
-
 def _moe_cfg(capacity_factor=1.0):
     cfg = reduced_config(get_config("mixtral-8x7b"), num_layers=4,
                          d_model=64, d_ff=128)
@@ -358,7 +349,6 @@ print("PASS", l0, l2, l4)
 
 
 @pytest.mark.slow
-@requires_modern_shard_map       # reduced mixtral: MoE grad, see above
 def test_session_relayout_is_loss_neutral():
     """The acceptance demo: a full Session train on the moe scenario with
     live re-layout ON fires at least one re-layout and produces the SAME

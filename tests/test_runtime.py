@@ -129,6 +129,6 @@ def test_compressed_psum_single_axis():
     from repro.pipeline.pipeline import _shard_map
     red, err = jax.jit(_shard_map(
         f, mesh=mesh, in_specs=jax.sharding.PartitionSpec(),
-        out_specs=jax.sharding.PartitionSpec(), axis_names={"d"}))(g)
+        out_specs=jax.sharding.PartitionSpec()))(g)
     np.testing.assert_allclose(np.asarray(red + err), np.asarray(g),
                                atol=1e-5)

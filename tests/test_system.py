@@ -8,25 +8,14 @@ These are the heavyweight integration gates:
   * mini multi-pod dry-run (AOT lower/compile on a (2,2,2) mesh with the
     production sharding rules — same code path as the 512-chip dry-run).
 """
-import jax
 import pytest
 
 from conftest import run_in_subprocess
 
-# grad-of-shard_map with MoE scalar residuals trips an upstream _SpecError
-# in jax<0.5's experimental shard_map transpose (its own error text says to
-# file a jax issue); the modern jax.shard_map path is fine.  Dense archs
-# grad correctly on both.
-requires_modern_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="MoE grad through jax.experimental.shard_map (jax<0.5) hits an "
-           "upstream _SpecError; needs jax.shard_map")
-
-
 @pytest.mark.slow
 @pytest.mark.parametrize("arch", [
     "smollm-360m",
-    pytest.param("mixtral-8x7b", marks=requires_modern_shard_map),
+    "mixtral-8x7b",
 ])
 def test_pipeline_equals_reference(arch):
     out = run_in_subprocess("""
@@ -183,7 +172,6 @@ print("PASS")
 
 
 @pytest.mark.slow
-@requires_modern_shard_map       # reduced mixtral: MoE grad, see above
 def test_mini_multipod_dryrun():
     out = run_in_subprocess("""
 import jax, jax.numpy as jnp
