@@ -115,53 +115,29 @@ def main_controller(quick: bool = False):
 
 
 # ---------------------------------------------------------------------------
-# observability overhead: tracing / metrics / in-step timing on-vs-off
+# observability overhead: host cost of spans, instants and metrics
 # ---------------------------------------------------------------------------
-_OBS_CHILD = """
-import json, statistics
-from repro.api import RunSpec, Session
-
-def steady(obs):
-    spec = RunSpec.from_dict({
-        "schema_version": 4,
-        "model": {"arch": "smollm-360m", "layers": 8, "d_model": 64,
-                  "num_heads": 4, "num_kv_heads": 2, "vocab_size": 256},
-        "parallel": {"stages": 4, "num_micro": 4, "mb_global": 4,
-                     "seq": 32},
-        "controller": {"rebalance_every": 4},
-        "obs": obs, "steps": %(steps)d, "log_every": 1000})
-    with Session(spec) as s:
-        rep = s.train()
-    return rep["timing"]["steady_step_mean_s"], spec.to_dict()
-
-base, spec = steady({})
-trace, _ = steady({"trace": True})
-instep, _ = steady({"in_step_timing": True})
-print("BENCH_JSON " + json.dumps(
-    {"baseline": base, "trace": trace, "in_step": instep, "spec": spec}))
-"""
-
-
 def run_obs(quick: bool = False):
-    """Observability layer overhead (DESIGN.md §15 acceptance numbers).
-
-    Host-side microbenches (span open/close, instant, counter inc,
-    histogram observe, unified-event stamping) run inline; the per-step
-    cost of tracing and in-step stage timing against a real pipelined
-    trainer runs in a subprocess on 4 forced host devices (same idiom as
-    ``bench_elastic``) — ``derived`` for the ``obs_step_*`` rows is the
-    relative per-step overhead vs the all-off baseline."""
-    import json as _json
-    import os
-    import subprocess
-    import sys
+    """Host-side microbenches of the observability layer (DESIGN.md §15):
+    a program span with tracing off (no profiler, no tracer), a tracer
+    span, a tracer instant, a counter inc, a histogram observe and a
+    unified-event stamp.  The per-step cost on the chip is measured by the
+    chip benchmark (PERF.md)."""
+    import jax  # noqa: F401  (spans open profiler annotations once loaded)
 
     from repro.obs.events import stamp_record
     from repro.obs.metrics import MetricsRegistry
-    from repro.obs.trace import Tracer
+    from repro.obs.trace import Tracer, span
 
     iters = 20000 if quick else 200000
     rows = []
+
+    t0 = time.perf_counter()
+    for i in range(iters):
+        with span("bench.span", step=i):
+            pass
+    dt = (time.perf_counter() - t0) / iters
+    rows.append(("obs_program_span_off", dt * 1e6, dt))
 
     tr = Tracer("bench")
     t0 = time.perf_counter()
@@ -196,35 +172,15 @@ def run_obs(quick: bool = False):
         stamp_record({"step": i}, source="session", kind="log", tracer=tr)
     dt = (time.perf_counter() - t0) / iters
     rows.append(("obs_stamp_record", dt * 1e6, dt))
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    src = os.path.join(repo, "src")
-    out = subprocess.run(
-        [sys.executable, "-c", _OBS_CHILD % {"steps": 10 if quick else 24}],
-        capture_output=True, text=True, timeout=1800,
-        env={**os.environ, "PYTHONPATH": src, "REPRO_TRAIN_DEVICES": "4",
-             "JAX_PLATFORMS": "cpu"})
-    if out.returncode != 0:
-        raise RuntimeError(f"obs step bench failed:\n{out.stdout[-2000:]}"
-                           f"\n{out.stderr[-2000:]}")
-    line = [ln for ln in out.stdout.splitlines()
-            if ln.startswith("BENCH_JSON ")][-1]
-    d = _json.loads(line[len("BENCH_JSON "):])
-    base = max(1e-12, d["baseline"])
-    rows.append(("obs_step_baseline", d["baseline"] * 1e6, d["baseline"]))
-    rows.append(("obs_step_trace_rel_overhead", d["trace"] * 1e6,
-                 d["trace"] / base - 1.0))
-    rows.append(("obs_step_in_step_timing_rel_overhead",
-                 d["in_step"] * 1e6, d["in_step"] / base - 1.0))
-    return rows, d["spec"]
+    return rows
 
 
 def main_obs(quick: bool = False):
-    rows, spec = run_obs(quick)
+    rows = run_obs(quick)
     print("name,us_per_call,derived")
     for name, us, derived in rows:
         print(f"{name},{us:.3f},{derived:.9f}")
-    return rows, spec
+    return rows
 
 
 if __name__ == "__main__":

@@ -45,6 +45,7 @@ from typing import Any, Dict, List, Optional
 from repro.api.specs import RunSpec
 from repro.obs.events import EVENT_SCHEMA, stamp_record
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.trace import span
 
 
 @dataclasses.dataclass
@@ -564,35 +565,62 @@ class Session:
         preempt_ctx = None
         warmup_steps, warmup_s, decide_s = 0, 0.0, 0.0
         steady_times: List[float] = []
-        root_span = (tracer.span("train", cat="session", steps=steps,
-                                 stages=stages) if tracer is not None
-                     else None)
+        # steady throughput runs from the end of the last warm-up step to
+        # the end of the last step, host work between steps included
+        t_steady0 = t_done = None
+        steady_since = 0
+        tiles_folded = 0
+
+        def fold_tiles() -> None:
+            nonlocal tiles_folded
+            tiles = engine.attn_tiles_total()
+            mreg.inc("dynmo_attn_tiles_total", tiles - tiles_folded,
+                     help="attention (query block, key block) tiles the "
+                          "block-sparse kernels computed, forward")
+            tiles_folded = tiles
+
+        def iterations():
+            """(step, batch) per loop iteration, which runs inside its
+            ``train.iter`` span; the loader's wait is ``train.data``."""
+            batches = iter(loader)
+            for step in range(start_step, steps):
+                with span("train.iter", cat="train", step=step):
+                    with span("train.data", cat="data"):
+                        batch = next(batches, None)
+                    if batch is None:
+                        return
+                    yield step, batch
+
+        root_span = span("train", cat="session", steps=steps, stages=stages)
         t0 = time.perf_counter()
-        for step, batch in enumerate(loader, start=start_step):
-            if step >= steps:
-                break
+        for step, batch in iterations():
             t_step = time.perf_counter()
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            lr = cosine_schedule(jnp.float32(step), steps, 3e-4, warmup=10)
-            sp_step = (tracer.span("train.step", cat="train", step=step,
-                                   stages=state.stages)
-                       if tracer is not None else None)
-            loss, stats, gnorm = engine.step(state, batch, lr)
-            # the step time ends when the device has finished the update;
-            # the per-slot stats tree stays on device until controller
-            # cadence (§3.3.1)
-            jax.block_until_ready((loss, state.params, state.opt_state))
-            losses.append(float(loss))
-            if sp_step is not None:
+            with span("train.batch", cat="data"):
+                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                lr = cosine_schedule(jnp.float32(step), steps, 3e-4,
+                                     warmup=10)
+            with span("train.step", cat="train", step=step,
+                      stages=state.stages) as sp_step:
+                loss, stats, gnorm = engine.step(state, batch, lr)
+                # the step time ends when the device has finished the
+                # update; the per-slot stats tree stays on device until
+                # controller cadence (§3.3.1)
+                with span("train.wait", cat="train"):
+                    jax.block_until_ready((loss, state.params,
+                                           state.opt_state))
+                    losses.append(float(loss))
                 sp_step.end(compiled=engine.last_step_compiled)
-            dt = time.perf_counter() - t_step
+            t_done = time.perf_counter()
+            dt = t_done - t_step
             step_times.append(dt)
             stages_hist.append(state.stages)
             if engine.last_step_compiled:
                 warmup_steps += 1
                 warmup_s += dt
+                t_steady0, steady_since = t_done, 0
             else:
                 steady_times.append(dt)
+                steady_since += 1
                 mreg.observe("dynmo_step_seconds", dt,
                              help="steady-state train step wall seconds")
             mreg.inc("dynmo_train_steps_total",
@@ -601,32 +629,33 @@ class Session:
                      help="current pipeline stage count")
 
             # ---- dynamism events (black-box to the controller)
-            if dynamism == "pruning" and step and step % 10 == 0:
-                sp = zhu_gupta_sparsity(
-                    step * 100, dataclasses.replace(
-                        dyncfg, prune_start_iter=0,
-                        prune_end_iter=steps * 100, prune_frequency=1))
-                keep = prn.target_keep_blocks(
-                    cfg, cfg.total_blocks(), sp)
-                dyn = dict(state.dyn)
-                dyn["ff_mask"] = prn.global_block_prune(
-                    cfg, state.params["stages"], state.assignment["tags"],
-                    keep)
-                state.dyn = dyn
-            if dynamism == "freezing" and step and step % 10 == 0:
-                front = int(cfg.total_blocks() * min(0.6, step / steps))
-                fr = np.zeros_like(np.asarray(state.dyn["frozen"]))
-                g = 0
-                tags_np = np.asarray(state.assignment["tags"])
-                for s in range(tags_np.shape[0]):
-                    for l in range(tags_np.shape[1]):
-                        if tags_np[s, l] != 0:
-                            if g < front:
-                                fr[s, l] = 1.0
-                            g += 1
-                dyn = dict(state.dyn)
-                dyn["frozen"] = jnp.asarray(fr)
-                state.dyn = dyn
+            with span("train.dynamism", cat="dynamics"):
+                if dynamism == "pruning" and step and step % 10 == 0:
+                    sp = zhu_gupta_sparsity(
+                        step * 100, dataclasses.replace(
+                            dyncfg, prune_start_iter=0,
+                            prune_end_iter=steps * 100, prune_frequency=1))
+                    keep = prn.target_keep_blocks(
+                        cfg, cfg.total_blocks(), sp)
+                    dyn = dict(state.dyn)
+                    dyn["ff_mask"] = prn.global_block_prune(
+                        cfg, state.params["stages"],
+                        state.assignment["tags"], keep)
+                    state.dyn = dyn
+                if dynamism == "freezing" and step and step % 10 == 0:
+                    front = int(cfg.total_blocks() * min(0.6, step / steps))
+                    fr = np.zeros_like(np.asarray(state.dyn["frozen"]))
+                    g = 0
+                    tags_np = np.asarray(state.assignment["tags"])
+                    for s in range(tags_np.shape[0]):
+                        for l in range(tags_np.shape[1]):
+                            if tags_np[s, l] != 0:
+                                if g < front:
+                                    fr[s, l] = 1.0
+                                g += 1
+                    dyn = dict(state.dyn)
+                    dyn["frozen"] = jnp.asarray(fr)
+                    state.dyn = dyn
 
             # ---- heartbeats (simulated per-step liveness: active workers
             # beat; released/dead ones go silent and time out)
@@ -646,9 +675,8 @@ class Session:
             # device→host stats sync; in async mode this is a pointer swap)
             if ctrl.cadence(step + 1):
                 t_decide = time.perf_counter()
-                sp_dec = (tracer.span("controller.decide", cat="controller",
-                                      step=step)
-                          if tracer is not None else None)
+                sp_dec = span("controller.decide", cat="controller",
+                              step=step)
                 measured = None
                 src = None
                 if obs.in_step_timing:
@@ -698,18 +726,21 @@ class Session:
                             share = np.asarray(state.lps, np.float64)
                             measured = share / share.sum() * step_times[-1]
                         measured = measured * np.asarray(mult)
-                cp.publish(StatsSnapshot(
-                    iteration=step + 1, epoch=engine.epoch,
-                    stats=engine.stats_to_host(state, stats),
-                    tags=np.asarray(state.assignment["tags"]),
-                    num_micro=shapes.num_micro, tokens=tokens_per_step,
-                    seq=seq, frozen=np.asarray(state.dyn["frozen"]),
-                    stage_times=measured))
-                if spec.controller.async_drain:
-                    cp.drain()
+                with span("controller.stats_to_host", cat="controller"):
+                    host_stats = engine.stats_to_host(state, stats)
+                    fold_tiles()
+                with span("controller.publish", cat="controller"):
+                    cp.publish(StatsSnapshot(
+                        iteration=step + 1, epoch=engine.epoch,
+                        stats=host_stats,
+                        tags=np.asarray(state.assignment["tags"]),
+                        num_micro=shapes.num_micro, tokens=tokens_per_step,
+                        seq=seq, frozen=np.asarray(state.dyn["frozen"]),
+                        stage_times=measured))
+                    if spec.controller.async_drain:
+                        cp.drain()
                 decide_s += time.perf_counter() - t_decide
-                if sp_dec is not None:
-                    sp_dec.end(source=src)
+                sp_dec.end(source=src)
 
             # ---- cluster-scheduler directives (multi-tenant): a steal by
             # a higher-priority tenant arrives as a preemption directive
@@ -787,16 +818,12 @@ class Session:
                                moved_layers=plan.event.moved_layers)
                 if (plan.resize is not None
                         and plan.resize.target_stages < state.stages):
-                    sp_rz = None
-                    if tracer is not None:
-                        parent = ((preempt_ctx or {}).get("span_id")
-                                  if plan.resize.policy == "preempt"
-                                  else None)
-                        sp_rz = tracer.span(
-                            "resize.shrink", cat="resize",
-                            parent_id=parent, step=step,
-                            policy=plan.resize.policy,
-                            target=plan.resize.target_stages)
+                    parent = ((preempt_ctx or {}).get("span_id")
+                              if plan.resize.policy == "preempt" else None)
+                    sp_rz = span("resize.shrink", cat="resize",
+                                 parent_id=parent, step=step,
+                                 policy=plan.resize.policy,
+                                 target=plan.resize.target_stages)
                     state = engine.shrink(state, plan.resize.target_stages,
                                           plan.resize.layers_per_stage,
                                           step=step)
@@ -804,10 +831,9 @@ class Session:
                     mreg.inc("dynmo_resizes_total", kind="shrink",
                              policy=plan.resize.policy,
                              help="engine resizes by kind")
-                    if sp_rz is not None:
-                        sp_rz.end(stages=state.stages)
-                        if plan.resize.policy == "preempt":
-                            preempt_ctx = None
+                    sp_rz.end(stages=state.stages)
+                    if plan.resize.policy == "preempt":
+                        preempt_ctx = None
                 elif plan.new_lps is not None:
                     p, o, d, new_assignment, _ = cp.apply(
                         plan, state.params, state.opt_state, state.dyn)
@@ -887,15 +913,13 @@ class Session:
                 ckpt.maybe_save(step, state.params, state.opt_state,
                                 state.dyn, state.lps)
             if safept is not None and safept.due(step):
-                sp_ck = (tracer.span("safepoint", cat="checkpoint",
-                                     step=step)
-                         if tracer is not None else None)
-                path = safept.save(
-                    step, state, spec=spec, engine=engine, scaler=scaler,
-                    repack_enabled=cp.with_ctrl(
-                        lambda c: bool(c.ccfg.repack)),
-                    jm_dir=self._jm_dir)
-                if sp_ck is not None:
+                with span("safepoint", cat="checkpoint",
+                          step=step) as sp_ck:
+                    path = safept.save(
+                        step, state, spec=spec, engine=engine,
+                        scaler=scaler, repack_enabled=cp.with_ctrl(
+                            lambda c: bool(c.ccfg.repack)),
+                        jm_dir=self._jm_dir)
                     sp_ck.end(path=path)
                 self._emit("safepoint", step, path=path,
                            stages=state.stages)
@@ -912,11 +936,11 @@ class Session:
                       f"gnorm {float(gnorm):.3f} S={state.stages} "
                       f"lps={state.lps}")
         wall = time.perf_counter() - t0
-        if root_span is not None:
-            root_span.end(steps_run=len(losses))
+        fold_tiles()
+        root_span.end(steps_run=len(losses))
         steady_s = float(sum(steady_times))
-        steady_tok_s = (tokens_per_step * len(steady_times) / steady_s
-                        if steady_s > 0 else None)
+        steady_tok_s = (tokens_per_step * steady_since
+                        / (t_done - t_steady0) if steady_since else None)
         if steady_tok_s is not None:
             mreg.set("dynmo_tokens_per_s", steady_tok_s,
                      help="steady-state training throughput")
@@ -1089,13 +1113,11 @@ class Session:
                             tracer=tracer, metrics=self.metrics,
                             paged=paged, temperature=s.temperature)
         self._server = srv
-        root_span = (tracer.span("serve", cat="session",
-                                 requests=len(trace))
-                     if tracer is not None else None)
-        report = srv.serve(trace, autoscale=spec.cluster.autoscale,
-                           resize_at=resize_at, max_ticks=s.max_ticks,
-                           injector=injector)
-        if root_span is not None:
+        with span("serve", cat="session",
+                  requests=len(trace)) as root_span:
+            report = srv.serve(trace, autoscale=spec.cluster.autoscale,
+                               resize_at=resize_at, max_ticks=s.max_ticks,
+                               injector=injector)
             root_span.end(ticks=report["ticks"],
                           completions=len(report["completions"]))
         self.metrics.set("dynmo_tokens_per_s", report["tokens_per_s"],

@@ -37,6 +37,7 @@ from repro.core.migration import apply_plan, build_plan
 from repro.dynamics.config import DynamicsConfig
 from repro.launch.mesh import make_submesh
 from repro.models import model as M
+from repro.obs.trace import span
 from repro.optim.optimizers import OptConfig, make_optimizer
 from repro.pipeline.pipeline import (PipelineShapes, build_decode_fn,
                                      build_loss_fn, build_prefill_fn)
@@ -69,6 +70,13 @@ def _pack_pages(pool, scratch_k, scratch_v, table, mask):
 def _copy_block(pool, src, dst):
     """Duplicate one physical block (CoW fork) in every stage-slot pool."""
     return {k: v.at[:, :, dst].set(v[:, :, src]) for k, v in pool.items()}
+
+
+@jax.jit
+def _add_tiles(total, tiles):
+    """``total`` plus a step's per-slot attention tiles (uint32; the sum
+    wraps past 2**32 tiles, so it is folded to the host well before)."""
+    return total + jnp.sum(tiles.astype(jnp.uint32))
 
 
 def make_train_step(cfg: ModelConfig, dcfg: DistConfig,
@@ -185,6 +193,11 @@ class ElasticEngine:
         self.temperature = float(temperature)
         self.last_step_compiled = False
         self.last_moe_drop = None   # serve telemetry (see _note_moe_drop)
+        # attention tiles of the train steps: a device-resident sum since
+        # the last fold into the host count (``attn_tiles_total``)
+        self._tiles_dev = None
+        self._tiles_world: Optional[EngineWorld] = None
+        self._tiles_host = 0
         self.devices = (list(devices) if devices is not None
                         else list(jax.devices()))
         if job_manager is None:
@@ -434,15 +447,32 @@ class ElasticEngine:
         # carry shardings the compiled step has not seen, and would make it
         # recompile; placing them onto the world's layout costs nothing for
         # leaves already there
-        (state.params, state.opt_state, state.dyn, state.assignment,
-         _) = self._place(w, state.params, state.opt_state, state.dyn,
-                          state.assignment)
-        with w.mesh:
-            params, opt_state, loss, stats, gnorm = w.step(
-                state.params, state.opt_state, state.assignment, state.dyn,
-                batch, lr)
-        state.params, state.opt_state = params, opt_state
+        with span("engine.place", cat="engine"):
+            (state.params, state.opt_state, state.dyn, state.assignment,
+             _) = self._place(w, state.params, state.opt_state, state.dyn,
+                              state.assignment)
+        with span("engine.dispatch", cat="engine"):
+            with w.mesh:
+                params, opt_state, loss, stats, gnorm = w.step(
+                    state.params, state.opt_state, state.assignment,
+                    state.dyn, batch, lr)
+            state.params, state.opt_state = params, opt_state
+            if self._tiles_world is not w:
+                self.attn_tiles_total()     # the old world's sum, folded
+                self._tiles_world = w
+            self._tiles_dev = _add_tiles(
+                self._tiles_dev if self._tiles_dev is not None
+                else jnp.uint32(0), stats["attn_tiles"])
         return loss, stats, gnorm
+
+    def attn_tiles_total(self) -> int:
+        """(query block, key block) tiles the attention kernels computed in
+        the forward of every train step so far, summed over layers and
+        sequences.  Reads the device sum: a host sync."""
+        if self._tiles_dev is not None:
+            self._tiles_host += int(self._tiles_dev)
+            self._tiles_dev = None
+        return self._tiles_host
 
     @staticmethod
     def stats_to_host(state: EngineState, stats):

@@ -33,7 +33,8 @@ from repro.models import mamba as mamba_lib
 from repro.models import xlstm as xlstm_lib
 from repro.models.layers import (
     apply_rope, decode_attention, expand_ff_mask as _expand_ff_mask,
-    flash_attention, gelu_mlp, pin_batch, rms_norm, swiglu,
+    attention_tiles, flash_attention, gelu_mlp, pin_batch, rms_norm,
+    swiglu,
 )
 
 PRUNE_BLOCK = 128      # block-structured pruning granularity (MXU tile width)
@@ -231,7 +232,8 @@ def stats_spec(cfg: ModelConfig) -> Dict[str, Any]:
     return dict(expert_load=_sds([E], jnp.float32),
                 moe_dropped=_sds([], jnp.float32),
                 ff_active=_sds([], jnp.float32),
-                attn_density=_sds([], jnp.float32))
+                attn_density=_sds([], jnp.float32),
+                attn_tiles=_sds([], jnp.float32))
 
 
 def _zero_stats(cfg: ModelConfig) -> Dict[str, jax.Array]:
@@ -326,11 +328,14 @@ def _attn_fwd(x, wq, wk, wv, wo, *, cfg, mode, cache, pos,
               kernel_impl: str = "scan"):
     """GQA attention with optional RoPE/SWA/bias/cache.  x: [mb, s, d];
     pos: [s] absolute positions (train/prefill) or scalar (decode).
-    Returns (out, new_cache, density)."""
+    Returns (out, new_cache, density, tiles): ``tiles`` counts the (query
+    block, key block) tiles the Pallas kernels compute in this forward
+    (``layers.attention_tiles``), 0 in decode."""
     m = _dims(cfg)
     nq, nkv, hd = m["nq"], m["nkv"], m["hd"]
     b, s, _ = x.shape
     density = jnp.float32(1.0)
+    tiles = jnp.float32(0.0)
     kv_block = 512
     if (dyncfg is not None and dyncfg.uses_sparse_attention
             and mode != "decode" and block_mask is None
@@ -429,6 +434,10 @@ def _attn_fwd(x, wq, wk, wv, wo, *, cfg, mode, cache, pos,
                               sliding_window=cfg.sliding_window,
                               block_mask=block_mask, kv_block=kv_block,
                               impl=kernel_impl)
+        tiles = attention_tiles(block_mask, b, s, xkv.shape[1],
+                                causal=causal, kv_block=kv_block,
+                                sliding_window=cfg.sliding_window,
+                                impl=kernel_impl)
         if mode == "prefill" and cache is not None:
             kc, vc = cache[cache_keys[0]], cache[cache_keys[1]]
             cap = kc.shape[1]
@@ -444,7 +453,7 @@ def _attn_fwd(x, wq, wk, wv, wo, *, cfg, mode, cache, pos,
     out = pin_batch(out.reshape(b, out.shape[1], nq * hd) @ wo)
     if bo is not None:
         out = out + bo
-    return out, new_cache, density
+    return out, new_cache, density, tiles
 
 
 # ---------------------------------------------------------------------------
@@ -569,7 +578,7 @@ def moe_ffn(p, x, cfg: ModelConfig, *, kernel_impl: str = "scan",
 # ---------------------------------------------------------------------------
 def _dense_block(p, x, *, cfg, mode, cache, pos, dyn, dyncfg,
                  kernel_impl="scan"):
-    h, cache, density = _attn_fwd(
+    h, cache, density, tiles = _attn_fwd(
         rms_norm(x, p["attn_norm"], cfg.norm_eps),
         p["wq"], p["wk"], p["wv"], p["wo"], cfg=cfg, mode=mode,
         cache=cache, pos=pos, dyncfg=dyncfg, kernel_impl=kernel_impl)
@@ -583,12 +592,13 @@ def _dense_block(p, x, *, cfg, mode, cache, pos, dyn, dyncfg,
     stats = _zero_stats(cfg)
     stats["ff_active"] = jnp.mean(dyn["ff_mask"])
     stats["attn_density"] = density
+    stats["attn_tiles"] = tiles
     return x, cache, stats, jnp.float32(0.0)
 
 
 def _moe_block(p, x, *, cfg, mode, cache, pos, dyn, dyncfg,
                kernel_impl="scan"):
-    h, cache, density = _attn_fwd(
+    h, cache, density, tiles = _attn_fwd(
         rms_norm(x, p["attn_norm"], cfg.norm_eps),
         p["wq"], p["wk"], p["wv"], p["wo"], cfg=cfg, mode=mode,
         cache=cache, pos=pos, dyncfg=dyncfg, kernel_impl=kernel_impl)
@@ -603,6 +613,7 @@ def _moe_block(p, x, *, cfg, mode, cache, pos, dyn, dyncfg,
     stats["moe_dropped"] = dropped
     stats["ff_active"] = jnp.float32(1.0)
     stats["attn_density"] = density
+    stats["attn_tiles"] = tiles
     return x, cache, stats, aux_loss
 
 
@@ -641,8 +652,9 @@ def _mamba_block(p, x, *, cfg, mode, cache, pos, dyn, shared=None,
         new_cache = dict(cache)
         new_cache["conv"] = conv_state.astype(cache["conv"].dtype)
         new_cache["ssm"] = ssm
+    tiles = jnp.float32(0.0)
     if with_shared_attn:
-        h, new_cache, _ = _attn_fwd(
+        h, new_cache, _, tiles = _attn_fwd(
             rms_norm(x, shared["ga_norm"], cfg.norm_eps),
             shared["ga_wq"], shared["ga_wk"], shared["ga_wv"],
             shared["ga_wo"], cfg=cfg, mode=mode,
@@ -651,6 +663,7 @@ def _mamba_block(p, x, *, cfg, mode, cache, pos, dyn, shared=None,
         x = x + h
     stats = _zero_stats(cfg)
     stats["ff_active"] = jnp.float32(1.0)
+    stats["attn_tiles"] = tiles
     return x, new_cache, stats, jnp.float32(0.0)
 
 
@@ -750,25 +763,25 @@ def _layer_norm(x, scale, bias, eps):
 
 
 def _enc_block(p, x, *, cfg, mode, cache, pos, dyn, kernel_impl="scan"):
-    h, _, _ = _attn_fwd(_layer_norm(x, p["e_ln1"], p["e_ln1b"], cfg.norm_eps),
-                        p["e_wq"], p["e_wk"], p["e_wv"], p["e_wo"],
-                        cfg=cfg, mode="train", cache=None,
-                        pos=jnp.arange(x.shape[1]), rope=False,
-                        causal=False, bq=p["e_bq"], bv=p["e_bv"],
-                        bo=p["e_bo"], kernel_impl=kernel_impl)
+    h, _, _, tiles = _attn_fwd(
+        _layer_norm(x, p["e_ln1"], p["e_ln1b"], cfg.norm_eps),
+        p["e_wq"], p["e_wk"], p["e_wv"], p["e_wo"], cfg=cfg, mode="train",
+        cache=None, pos=jnp.arange(x.shape[1]), rope=False, causal=False,
+        bq=p["e_bq"], bv=p["e_bv"], bo=p["e_bo"], kernel_impl=kernel_impl)
     x = x + h
     hn = _layer_norm(x, p["e_ln2"], p["e_ln2b"], cfg.norm_eps)
     x = x + gelu_mlp(hn, p["e_w1"], p["e_b1"], p["e_w2"], p["e_b2"],
                      dyn["ff_mask"], impl=kernel_impl)
     stats = _zero_stats(cfg)
     stats["ff_active"] = jnp.mean(dyn["ff_mask"])
+    stats["attn_tiles"] = tiles
     return x, cache, stats, jnp.float32(0.0)
 
 
 def _dec_block(p, x, *, cfg, mode, cache, pos, dyn, enc_out,
                kernel_impl="scan"):
     # self attention (causal, learned positions added at embedding)
-    h, cache, _ = _attn_fwd(
+    h, cache, _, tiles = _attn_fwd(
         _layer_norm(x, p["d_ln1"], p["d_ln1b"], cfg.norm_eps),
         p["d_wq"], p["d_wk"], p["d_wv"], p["d_wo"],
         cfg=cfg, mode=mode, cache=cache, pos=pos, rope=False,
@@ -788,17 +801,19 @@ def _dec_block(p, x, *, cfg, mode, cache, pos, dyn, enc_out,
             + p["c_bo"]
         new_cache = cache
     else:
-        h, new_cache, _ = _attn_fwd(
+        h, new_cache, _, cross_tiles = _attn_fwd(
             hn, p["c_wq"], p["c_wk"], p["c_wv"], p["c_wo"], cfg=cfg,
             mode=mode, cache=cache, pos=pos, rope=False, causal=False,
             bq=p["c_bq"], bv=p["c_bv"], bo=p["c_bo"], kv_override=enc_out,
             cache_keys=("ck", "cv"), kernel_impl=kernel_impl)
+        tiles = tiles + cross_tiles
     x = x + h
     hn = _layer_norm(x, p["d_ln3"], p["d_ln3b"], cfg.norm_eps)
     x = x + gelu_mlp(hn, p["d_w1"], p["d_b1"], p["d_w2"], p["d_b2"],
                      dyn["ff_mask"], impl=kernel_impl)
     stats = _zero_stats(cfg)
     stats["ff_active"] = jnp.mean(dyn["ff_mask"])
+    stats["attn_tiles"] = tiles
     return x, new_cache, stats, jnp.float32(0.0)
 
 

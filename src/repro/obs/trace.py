@@ -18,15 +18,25 @@ The module-level *current tracer* is how deep layers (RPC clients, the
 control plane, the fault injector) find the session's tracer without
 threading it through every constructor.  It is process-global on
 purpose — the async controller thread and HTTP client calls must see it.
-Stdlib-only: safe to import in manager processes that never load jax.
+
+``span(name)`` is the program's one way to mark host work.  It opens a
+JAX profiler annotation ``dynmo.<name>`` (recorded only while a profiler
+session runs, on the profiler's clock, beside the device's operations)
+and, when a tracer is current, a tracer span under the plain ``<name>``.
+With neither, it costs a few microseconds.
+Stdlib-only: safe to import in manager processes that never load jax
+(the annotation is opened only once the process has imported jax).
 """
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+PROFILER_PREFIX = "dynmo."
 
 _lock = threading.Lock()
 _current: Optional["Tracer"] = None
@@ -40,6 +50,44 @@ def set_current_tracer(tracer: Optional["Tracer"]) -> None:
 
 def current_tracer() -> Optional["Tracer"]:
     return _current
+
+
+class ProgramSpan:
+    """A profiler annotation and, when a tracer is current, a tracer span
+    over the same host work; a context manager, or ``end()`` it."""
+
+    __slots__ = ("_annotation", "_span")
+
+    def __init__(self, name: str, cat: str, parent_id: Optional[str],
+                 args: Dict[str, Any]):
+        profiler = sys.modules.get("jax.profiler")
+        self._annotation = None
+        if profiler is not None:
+            self._annotation = profiler.TraceAnnotation(PROFILER_PREFIX + name)
+            self._annotation.__enter__()
+        tracer = _current
+        self._span = (tracer.span(name, cat, parent_id, **args)
+                      if tracer is not None else None)
+
+    def end(self, **extra_args) -> None:
+        if self._span is not None:
+            self._span.end(**extra_args)
+        annotation, self._annotation = self._annotation, None
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
+
+    def __enter__(self) -> "ProgramSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
+def span(name: str, cat: str = "session", parent_id: Optional[str] = None,
+         **args) -> ProgramSpan:
+    """Mark host work ``name``: ``dynmo.<name>`` in the profiler's trace,
+    ``<name>`` in the current tracer (args go to the tracer only)."""
+    return ProgramSpan(name, cat, parent_id, args)
 
 
 class Span:

@@ -29,6 +29,7 @@ from repro.kernels.paged_attention import paged_tile_work
 from repro.configs.base import DistConfig, ModelConfig
 from repro.dynamics.config import DynamicsConfig
 from repro.launch.engine import ElasticEngine
+from repro.obs.trace import span
 from repro.pipeline.pipeline import PipelineShapes
 from repro.serve.requests import Request, RequestQueue
 from repro.serve.scheduler import Scheduler
@@ -152,10 +153,8 @@ class ElasticServer:
         scheduler (no-op on single-tenant managers)."""
         st = self.state
         prev = st.stages
-        sp = (self.tracer.span("serve.resize", cat="resize", tick=tick,
-                               target=target_stages, reason=reason,
-                               steal=steal)
-              if self.tracer is not None else None)
+        sp = span("serve.resize", cat="resize", tick=tick,
+                  target=target_stages, reason=reason, steal=steal)
         if target_stages < prev:
             self.state = self.engine.shrink(st, target_stages, step=tick)
         elif target_stages > prev:
@@ -165,8 +164,7 @@ class ElasticServer:
             self.state = self.engine.grow(st, target_stages - prev,
                                           step=tick, steal=steal)
         changed = self.state.stages != prev
-        if sp is not None:
-            sp.end(stages=self.state.stages, changed=changed)
+        sp.end(stages=self.state.stages, changed=changed)
         if self.metrics is not None and changed:
             rz = self.engine.resizes[-1]
             self.metrics.inc("dynmo_resizes_total", kind=rz.kind,
@@ -225,10 +223,8 @@ class ElasticServer:
         while tick < max_ticks and not sched.done:
             t0 = time.perf_counter()
             emitted = 0
-            sp_tick = (self.tracer.span("serve.tick", cat="serve",
-                                        tick=tick,
-                                        stages=self.state.stages)
-                       if self.tracer is not None else None)
+            sp_tick = span("serve.tick", cat="serve", tick=tick,
+                           stages=self.state.stages)
             adm = sched.plan_admissions(tick)
             if adm is not None and self.tracer is not None:
                 self.tracer.instant("serve.admit", cat="serve", tick=tick,
@@ -286,8 +282,7 @@ class ElasticServer:
                 self.state.cache = _permute_lanes(self.state.cache, perm,
                                                   m, B)
             wall = time.perf_counter() - t0
-            if sp_tick is not None:
-                sp_tick.end(tokens=emitted, queue=sched.queue_depth)
+            sp_tick.end(tokens=emitted, queue=sched.queue_depth)
             tick_wall.append(wall)
             tick_tokens.append(emitted)
             token_lat.extend([wall] * emitted)

@@ -230,3 +230,40 @@ assert sum(post) / len(post) < sum(pre) / len(pre), (pre, post)
 print("PASS", out["losses"][0], "->", out["losses"][-1])
 """, devices=4, timeout=900)
     assert "PASS" in out
+
+
+@pytest.mark.parametrize("kind", ["sparse_attention", "none"])
+def test_engine_counts_the_attention_tiles_of_its_steps(kind):
+    """``ElasticEngine.attn_tiles_total`` sums each layer's live tiles over
+    the steps' sequences exactly.  Sparse: the mask sum of
+    ``hash_block_mask``, which each layer's density reports (the density
+    stat sums, over micro-batches, the mean over rows of the mask sum over
+    its causal tiles).  Dense: the Pallas kernels skip the tiles above the
+    diagonal, so each sequence costs nb(nb + 1)/2 tiles of 128 tokens."""
+    from repro.data.loader import DataConfig, make_loader
+    from repro.launch.engine import ElasticEngine
+    from repro.pipeline.pipeline import PipelineShapes
+    layers, num_micro, mb, seq, block, steps = 2, 2, 2, 256, 64, 2
+    cfg = reduced_config(get_config("smollm-360m"), num_layers=layers,
+                         d_model=64, num_heads=4, num_kv_heads=2, d_ff=128,
+                         vocab_size=128)
+    dcfg = DistConfig(num_stages=1, slot_slack=0, remat="block",
+                      param_dtype="float32", kernel_impl="pallas")
+    engine = ElasticEngine(
+        cfg, dcfg, DynamicsConfig(kind=kind, sparse_block=block,
+                                  sparse_nbuckets=4),
+        PipelineShapes(num_micro=num_micro, mb_global=mb, seq=seq))
+    state = engine.init_state(jax.random.PRNGKey(0))
+    loader = make_loader(cfg, DataConfig(num_micro, mb, seq, seed=0))
+    want = 0
+    for _ in range(steps):
+        _, stats, _ = engine.step(state, next(loader), jnp.float32(1e-3))
+        if kind == "sparse_attention":
+            nb = seq // block
+            want += int(np.rint(np.asarray(stats["attn_density"])
+                                * nb * (nb + 1) / 2 * mb).sum())
+        else:
+            nb = seq // 128
+            want += layers * num_micro * mb * nb * (nb + 1) // 2
+    assert engine.attn_tiles_total() == want
+    assert engine.attn_tiles_total() == want    # folded once, not twice

@@ -118,3 +118,32 @@ def test_pruned_matmul_matches_model_semantics():
     model = swiglu(x, wi, wg, wo, jnp.repeat(mask.astype(jnp.float32), 64))
     np.testing.assert_allclose(np.asarray(kern), np.asarray(model),
                                atol=1e-4)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_attention_tiles_count_the_kernels_live_tiles(causal):
+    """``layers.attention_tiles`` counts, per head, the tiles the Pallas
+    kernels compute: on a hash mask, the mask's own sum (the mask is
+    already causal, and at equal blocks the kernels' causal gate keeps
+    exactly the tiles on or below the diagonal); without a mask, at the
+    kernels' 128-token blocks, every causal tile of every sequence, or
+    every tile when not causal; nothing on the scan path."""
+    from repro.models.blocks import hash_block_mask
+    from repro.models.layers import attention_tiles
+    b, s, block = 3, 512, 64
+    x = jnp.asarray(np.random.RandomState(1).randn(b, s, 32), jnp.float32)
+    mask, density = hash_block_mask(x, nbuckets=4, block=block,
+                                    causal=causal)
+    got = attention_tiles(mask, b, s, s, causal=causal, kv_block=block,
+                          impl="pallas")
+    nb = s // block
+    assert float(got) == float(jnp.sum(mask))
+    causal_tiles = nb * (nb + 1) / 2 if causal else nb * nb
+    assert float(got) == pytest.approx(float(density) * causal_tiles * b)
+    dense = attention_tiles(None, b, s, s, causal=causal, kv_block=512,
+                            impl="pallas")
+    n128 = s // 128
+    assert float(dense) == b * (n128 * (n128 + 1) // 2 if causal
+                                else n128 * n128)
+    assert float(attention_tiles(mask, b, s, s, causal=causal,
+                                 kv_block=block, impl="scan")) == 0.0

@@ -138,6 +138,108 @@ def test_current_tracer_is_process_global():
     assert current_tracer() is None
 
 
+def test_program_span_records_into_the_current_tracer_and_nests():
+    """``obs.trace.span`` records into the current tracer under the plain
+    name, nests by thread, takes ``end(**args)`` once, and records nothing
+    with no tracer set."""
+    from repro.obs.trace import span
+    with span("train.iter") as sp:
+        pass
+    sp.end()                                 # no tracer: nothing, no error
+    tr = Tracer("spans", pid=0)
+    set_current_tracer(tr)
+    try:
+        with span("train", steps=2):
+            with span("train.step", cat="train", step=0) as st:
+                inner = span("engine.place", cat="engine")
+                inner.end(leaves=3)
+                inner.end(leaves=4)          # a second end is a no-op
+                st.end(compiled=True)
+    finally:
+        set_current_tracer(None)
+    evs = {e["name"]: e for e in tr.to_chrome()["traceEvents"]}
+    assert sorted(evs) == ["engine.place", "train", "train.step"]
+    ids = {n: e["args"]["span_id"] for n, e in evs.items()}
+    assert evs["train.step"]["args"]["parent_id"] == ids["train"]
+    assert evs["engine.place"]["args"]["parent_id"] == ids["train.step"]
+    assert evs["engine.place"]["args"]["leaves"] == 3
+    assert evs["train.step"]["args"]["compiled"] is True
+    assert evs["train.step"]["cat"] == "train"
+
+
+_SESSION_2_STEPS = """
+import glob, json, os, tempfile
+import jax
+from jax.profiler import ProfileData
+from repro.api import RunSpec, Session
+from repro.obs import trace as obs_trace
+
+made = []
+init = obs_trace.Tracer.__init__
+def counting_init(self, *a, **k):
+    made.append(a)
+    init(self, *a, **k)
+obs_trace.Tracer.__init__ = counting_init
+
+spec = RunSpec.from_dict({
+    "model": {"arch": "smollm-360m", "layers": 2, "d_model": 64,
+              "num_heads": 4, "num_kv_heads": 2, "vocab_size": 128},
+    "parallel": {"stages": 1, "num_micro": 1, "mb_global": 1, "seq": 32},
+    "controller": {"rebalance_every": 1},
+    "steps": %(steps)d, "log_every": 1000})
+profile = %(profile)r
+d = tempfile.mkdtemp()
+with Session(spec) as s:
+    if profile:
+        jax.profiler.start_trace(d)
+    rep = s.train()
+    if profile:
+        jax.profiler.stop_trace()
+    assert s.tracer is None and obs_trace.current_tracer() is None
+assert made == [], made
+names = set()
+if profile:
+    path = glob.glob(os.path.join(d, "**", "*.xplane.pb"), recursive=True)[0]
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            names |= {ev.name for ev in line.events
+                      if ev.name.startswith("dynmo.")}
+print("RESULT " + json.dumps({"names": sorted(names), **rep["timing"]}))
+"""
+
+
+def _result(out: str) -> dict:
+    line = [ln for ln in out.splitlines() if ln.startswith("RESULT ")][-1]
+    return json.loads(line[len("RESULT "):])
+
+
+def test_session_spans_land_in_the_profiler_trace():
+    """A 2-step ``Session.train`` under the JAX profiler, with no tracer:
+    the profiler's trace holds the program's spans as ``dynmo.<name>``."""
+    out = run_in_subprocess(_SESSION_2_STEPS % {"steps": 2, "profile": True},
+                            devices=1, timeout=600)
+    names = _result(out)["names"]
+    assert set(names) >= {
+        "dynmo.train", "dynmo.train.iter", "dynmo.train.data",
+        "dynmo.train.batch", "dynmo.train.step", "dynmo.engine.place",
+        "dynmo.engine.dispatch", "dynmo.train.wait", "dynmo.train.dynamism",
+        "dynmo.controller.decide", "dynmo.controller.stats_to_host",
+        "dynmo.controller.publish"}, names
+
+
+def test_train_without_tracing_makes_no_tracer_and_counts_host_work():
+    """With no profiler and ``obs.trace`` off, ``Session.train`` makes no
+    ``Tracer`` (so records no tracer event), and its steady throughput
+    counts the host work between steady steps: it is no higher than the
+    tokens over the steady steps' own times."""
+    out = run_in_subprocess(_SESSION_2_STEPS % {"steps": 4,
+                                                "profile": False},
+                            devices=1, timeout=600)
+    r = _result(out)
+    assert r["names"] == [] and r["steady_steps"] == 3
+    assert 0 < r["steady_tokens_per_s"] <= 32 * 3 / r["steady_s"]
+
+
 # ---------------------------------------------------------------------------
 # metrics: snapshot golden + exposition + endpoint
 # ---------------------------------------------------------------------------
