@@ -89,25 +89,26 @@ def swiglu(x: jax.Array, wi: jax.Array, wg: jax.Array, wo: jax.Array,
     (kernels.pruned_matmul): pruned blocks skip MXU tiles in forward AND
     backward.  The pallas path needs the block-level mask (granularity =
     d_ff // n_blocks); the dense paths accept either and expand.  Single-
-    token calls (decode) stay dense — padding 1 row to a 128-tile wastes
-    the MXU, mirroring the decode_attention special case."""
+    token calls (decode) stay dense — a 1-row kernel call wastes the MXU,
+    mirroring the decode_attention special case."""
     assert impl in KERNEL_IMPLS, impl
     d_ff = wi.shape[1]
     if impl == "pallas" and x.shape[-2] > 1:
         from repro.kernels.pruned_matmul import pruned_swiglu
         if ff_mask is None:
-            bmask, bf = jnp.ones((1,), jnp.float32), d_ff
+            bmask = jnp.ones((1,), jnp.float32)
         else:
             nb = ff_mask.shape[0]
-            # an expanded [d_ff] mask would pass divisibility with bf=1 —
-            # width-1 "blocks" defeat the MXU tiling; demand block-level
+            # an expanded [d_ff] mask would pass divisibility with blocks
+            # of 1 — width-1 blocks defeat the MXU tiling; demand
+            # block-level
             assert nb < d_ff and d_ff % nb == 0, (
                 "pallas swiglu needs a block-level ff_mask",
                 ff_mask.shape, d_ff)
-            bmask, bf = ff_mask, d_ff // nb
+            bmask = ff_mask
         if interpret is None:
             interpret = kernels.use_interpret()
-        return pin_batch(pruned_swiglu(x, wi, wg, wo, bmask, bf=bf,
+        return pin_batch(pruned_swiglu(x, wi, wg, wo, bmask,
                                        interpret=interpret))
     h = pin_batch(jax.nn.silu(x @ wg) * (x @ wi))
     if ff_mask is not None:
@@ -141,10 +142,10 @@ def gelu_mlp(x: jax.Array, w1: jax.Array, b1: jax.Array, w2: jax.Array,
         bf = d_ff // nb
         if interpret is None:
             interpret = kernels.use_interpret()
-        h = pruned_matmul(x, w1, bmask, mask_axis="n", bn=bf,
+        h = pruned_matmul(x, w1, bmask, mask_axis="n",
                           interpret=interpret) + b1
         h = jax.nn.gelu(h) * jnp.repeat(bmask, bf).astype(x.dtype)
-        return pruned_matmul(h, w2, bmask, mask_axis="k", bk=bf,
+        return pruned_matmul(h, w2, bmask, mask_axis="k",
                              interpret=interpret) + b2
     h = jax.nn.gelu(x @ w1 + b1)
     if ff_mask is not None:

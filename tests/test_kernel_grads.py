@@ -16,6 +16,7 @@ from repro.kernels.block_sparse_attention import (attention_tile_work,
 from repro.kernels.pruned_matmul import (matmul_tile_work, pruned_matmul,
                                          pruned_matmul_ref, pruned_swiglu,
                                          pruned_swiglu_ref)
+from repro.kernels.pruned_matmul.pruned_matmul import choose_tiles
 from repro.models.layers import flash_attention, swiglu
 
 NEG_INF = -1e30
@@ -137,15 +138,28 @@ def test_pruned_matmul_grad_parity(mask_axis, density):
         assert np.all(dw[:, keep * 128:] == 0)
 
 
-@pytest.mark.parametrize("density", [1.0, 0.5, 0.25])
-def test_pruned_swiglu_grad_parity(density):
-    rng = np.random.RandomState(int(density * 10) + 1)
-    M, d, ff = 64, 128, 512
+# (density, tokens M, d, d_ff, mask block, seed): the first three at
+# 128-wide blocks and their own seeds; then a d off the 128 grid, one
+# unpruned block wider than the d_ff tile the kernel chooses, and a pruned
+# mask with M off the token tile
+SWIGLU_GRAD_CASES = [
+    pytest.param(1.0, 64, 128, 512, 128, 11, id="1.0"),
+    pytest.param(0.5, 64, 128, 512, 128, 6, id="0.5"),
+    pytest.param(0.25, 64, 128, 512, 128, 3, id="0.25"),
+    pytest.param(0.5, 64, 192, 512, 128, 262, id="d192"),
+    pytest.param(1.0, 256, 1024, 4096, 4096, 1291, id="block-wider-than-tile"),
+    pytest.param(0.5, 2100, 192, 512, 128, 2298, id="pruned-M2100"),
+]
+
+
+@pytest.mark.parametrize("density,M,d,ff,bf,seed", SWIGLU_GRAD_CASES)
+def test_pruned_swiglu_grad_parity(density, M, d, ff, bf, seed):
+    rng = np.random.RandomState(seed)
     x = jnp.asarray(rng.randn(M, d) * 0.3, jnp.float32)
     wi = jnp.asarray(rng.randn(d, ff) * 0.05, jnp.float32)
     wg = jnp.asarray(rng.randn(d, ff) * 0.05, jnp.float32)
     wo = jnp.asarray(rng.randn(ff, d) * 0.05, jnp.float32)
-    nb = ff // 128
+    nb = ff // bf
     keep = max(1, int(round(nb * density)))
     mask = jnp.asarray([1] * keep + [0] * (nb - keep), jnp.int32)
 
@@ -154,7 +168,7 @@ def test_pruned_swiglu_grad_parity(density):
                                      interpret=True) ** 2)
 
     def loss_r(x, wi, wg, wo):
-        return jnp.sum(pruned_swiglu_ref(x, wi, wg, wo, mask) ** 2)
+        return jnp.sum(pruned_swiglu_ref(x, wi, wg, wo, mask, bf=bf) ** 2)
 
     gk = jax.grad(loss_k, (0, 1, 2, 3))(x, wi, wg, wo)
     gr = jax.grad(loss_r, (0, 1, 2, 3))(x, wi, wg, wo)
@@ -201,8 +215,18 @@ def test_tile_work_helpers_match_manual_count():
 
     pm = matmul_tile_work(256, 512, 512, np.asarray([1, 0, 1, 0]),
                           mask_axis="n")
-    assert pm["fwd_total"] == 2 * 4 * 4
+    # the kernels' grids at the tiles they choose (each a 256³ or
+    # 256x256x512 tile; a tile over several mask blocks skips the dead
+    # ones inside, so the live share of the work is the mask's)
+    t = choose_tiles(256, 512, 512, "n", 128)
+    assert (t.bm, t.bk, t.bn) == (256, 256, 512)
+    assert pm["fwd_total"] == 1 * 2 * 1
     assert pm["fwd_active"] == pm["fwd_total"] * 0.5
+    dx = choose_tiles(256, 512, 512, "k", 128)      # [M,N] @ [N,K]
+    dw = choose_tiles(512, 256, 512, "n", 128)      # [K,M] @ [M,N]
+    assert (dx.bm, dx.bk, dx.bn, dw.bm, dw.bk, dw.bn) == (
+        256, 256, 512, 256, 256, 512)
+    assert pm["bwd_total"] == 1 * 2 * 1 + 2 * 1 * 1
     assert pm["bwd_active"] / pm["bwd_total"] == 0.5
 
 

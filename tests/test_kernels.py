@@ -10,6 +10,13 @@ from repro.kernels.block_sparse_attention import (block_sparse_attention,
                                                   block_sparse_attention_ref)
 from repro.kernels.pruned_matmul import (pruned_matmul, pruned_matmul_ref,
                                          pruned_swiglu, pruned_swiglu_ref)
+from repro.kernels.pruned_matmul.pruned_matmul import (FULL_AXIS,
+                                                       MIN_SPLIT,
+                                                       VMEM_BUDGET, Tiles,
+                                                       choose_tiles,
+                                                       padded_extent,
+                                                       pruned_matmul_p,
+                                                       restream, tile_cost)
 
 
 def _bsa_ref_from_bhsd(q, k, v, mask, causal, bq, bk):
@@ -89,19 +96,110 @@ def test_pruned_matmul_sweep(M, K, N, mask_axis, dtype):
                                rtol=1e-2)
 
 
-@pytest.mark.parametrize("sparsity", [0.0, 0.5, 0.9])
-def test_pruned_swiglu(sparsity):
-    rng = np.random.RandomState(int(sparsity * 10))
-    M, d, ff = 64, 128, 512
+# (sparsity, tokens M, d, d_ff, mask block, seed): the first three at
+# 128-wide blocks, drawn as they always were (seed 9 draws every block
+# dead); then a d off the 128 grid, one unpruned block wider than the d_ff
+# tile the kernel chooses, and a pruned mask with M off the token tile,
+# each with its first block held live; and a mask with every block dead
+SWIGLU_CASES = [
+    pytest.param(0.0, 64, 128, 512, 128, 0, id="0.0"),
+    pytest.param(0.5, 64, 128, 512, 128, 5, id="0.5"),
+    pytest.param(0.9, 64, 128, 512, 128, 9, id="0.9"),
+    pytest.param(0.5, 64, 192, 512, 128, 261, id="d192"),
+    pytest.param(0.0, 256, 1024, 4096, 4096, 1280, id="block-wider-than-tile"),
+    pytest.param(0.5, 2100, 192, 512, 128, 2297, id="pruned-M2100"),
+    pytest.param(1.0, 2100, 192, 512, 128, 2302, id="all-pruned-M2100"),
+]
+
+
+@pytest.mark.parametrize("sparsity,M,d,ff,bf,seed", SWIGLU_CASES)
+def test_pruned_swiglu(sparsity, M, d, ff, bf, seed):
+    if bf == ff:
+        # the case's premise: the kernel cuts the single block in tiles
+        assert choose_tiles(M, d, ff, "n", bf).bn < ff
+    if M > FULL_AXIS and M % MIN_SPLIT:
+        assert padded_extent(M) > M
+    rng = np.random.RandomState(seed)
     x = jnp.asarray(rng.randn(M, d) * 0.3, jnp.float32)
     wi = jnp.asarray(rng.randn(d, ff) * 0.05, jnp.float32)
     wg = jnp.asarray(rng.randn(d, ff) * 0.05, jnp.float32)
     wo = jnp.asarray(rng.randn(ff, d) * 0.05, jnp.float32)
-    nb = ff // 128
-    mask = jnp.asarray((rng.rand(nb) >= sparsity).astype(np.int32))
+    nb = ff // bf
+    mask = (rng.rand(nb) >= sparsity).astype(np.int32)
+    if (M, d, ff) != (64, 128, 512) and sparsity < 1:
+        mask[0] = 1
+    mask = jnp.asarray(mask)
     out = pruned_swiglu(x, wi, wg, wo, mask, interpret=True)
-    ref = pruned_swiglu_ref(x, wi, wg, wo, mask)
+    ref = pruned_swiglu_ref(x, wi, wg, wo, mask, bf=bf)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
+    if not np.asarray(mask).any():
+        assert np.all(np.asarray(out) == 0)
+
+
+# smollm-360m's FFN at 8192 tokens: the three products of the forward and
+# backward (M, K, N, mask axis), unpruned (one block of d_ff) and pruned
+# at 128-wide blocks
+_T, _D, _F = 8192, 960, 2560
+FFN_PRODUCTS = {"up": (_T, _D, _F, "n"), "down": (_T, _F, _D, "k"),
+                "up_dw": (_D, _T, _F, "n"), "down_dw": (_F, _T, _D, "m")}
+
+
+@pytest.mark.parametrize("block", [_F, 128])
+@pytest.mark.parametrize("product", sorted(FFN_PRODUCTS))
+def test_choose_tiles_bounds_restream_and_vmem(product, block):
+    """At the real widths each masked tile divides its mask block or is a
+    whole number of them (skipped block by block inside the kernel), the
+    blocks fit the 16 MiB of scoped VMEM, and no operand is read from HBM
+    more than 8 times a call."""
+    M, K, N, ax = FFN_PRODUCTS[product]
+    t = choose_tiles(M, K, N, ax, block)
+    b = {"m": t.bm, "k": t.bk, "n": t.bn}
+    ext = {"m": M, "k": K, "n": N}
+    assert block % b[ax] == 0 or b[ax] % block == 0
+    assert all(ext[a] % b[a] == 0 for a in "mkn")
+    cost = tile_cost(M, K, N, t, 4)
+    assert cost.vmem <= VMEM_BUDGET < 16 << 20
+    n = {a: ext[a] // b[a] for a in "mkn"}
+    assert cost.restream == max(restream(t.order, n, "mk"),
+                                restream(t.order, n, "kn"))
+    assert cost.restream <= 8
+
+
+@pytest.mark.parametrize("tile", [128, 256, 512])
+@pytest.mark.parametrize("mask_axis,x_t,w_t", [
+    ("n", False, False), ("k", False, False), ("k", False, True),
+    ("n", False, True), ("n", True, False), ("m", True, False)])
+def test_pruned_matmul_p_tilings(mask_axis, x_t, w_t, tile):
+    """The kernel at explicit tiles, the masked one within a 256-wide mask
+    block (128), equal to it, or over two blocks (512), each operand read
+    as stored or transposed: equal to the masked dense product, with
+    exact zeros in dead output blocks."""
+    rng = np.random.RandomState(tile + len(mask_axis) + 2 * x_t + w_t)
+    M, K, N, mb = 512, 512, 1024, 256
+    ext = {"m": M, "k": K, "n": N}
+    x = rng.randn(M, K).astype(np.float32)
+    w = rng.randn(K, N).astype(np.float32)
+    mask = np.asarray([1, 0, 1, 1, 0, 0, 1, 0])[:ext[mask_axis] // mb]
+    b = {"m": 128, "k": 128, "n": 256}
+    b[mask_axis] = tile
+    out = pruned_matmul_p(
+        jnp.asarray(x.T if x_t else x), jnp.asarray(w.T if w_t else w),
+        jnp.asarray(mask), mask_axis=mask_axis, mask_block=mb, x_t=x_t,
+        w_t=w_t, _tiles=Tiles(b["m"], b["k"], b["n"], "nmk"),
+        interpret=True)
+    keep = np.repeat(mask, mb).astype(np.float32)
+    if mask_axis == "k":
+        ref = (x * keep) @ w
+    elif mask_axis == "n":
+        ref = (x @ w) * keep
+    else:
+        ref = (x @ w) * keep[:, None]
+    np.testing.assert_allclose(np.asarray(out), ref, atol=2e-3, rtol=1e-3)
+    if mask_axis != "k":
+        dead = keep == 0
+        out = np.asarray(out)
+        assert np.all((out[:, dead] if mask_axis == "n"
+                       else out[dead]) == 0)
 
 
 def test_pruned_matmul_matches_model_semantics():
@@ -114,7 +212,7 @@ def test_pruned_matmul_matches_model_semantics():
     wg = jnp.asarray(rng.randn(d, ff) * 0.05, jnp.float32)
     wo = jnp.asarray(rng.randn(ff, d) * 0.05, jnp.float32)
     mask = jnp.asarray([1, 0, 1, 1], jnp.int32)     # 4 blocks of 64 = ff 256
-    kern = pruned_swiglu(x, wi, wg, wo, mask, bf=64, interpret=True)
+    kern = pruned_swiglu(x, wi, wg, wo, mask, interpret=True)
     model = swiglu(x, wi, wg, wo, jnp.repeat(mask.astype(jnp.float32), 64))
     np.testing.assert_allclose(np.asarray(kern), np.asarray(model),
                                atol=1e-4)

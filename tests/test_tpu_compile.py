@@ -83,12 +83,74 @@ def test_pruned_swiglu_grad_compiles(one_chip):
     d, f = 1024, 4096
 
     def loss(x, wi, wg, wo, mask):
-        return jnp.sum(pruned_swiglu(x, wi, wg, wo, mask, bf=128,
+        return jnp.sum(pruned_swiglu(x, wi, wg, wo, mask,
                                      interpret=False) ** 2)
     _compiled_text(jax.grad(loss, argnums=(0, 1, 2, 3)),
                    _sds(one_chip, (1, SEQ, d)), _sds(one_chip, (d, f)),
                    _sds(one_chip, (d, f)), _sds(one_chip, (f, d)),
                    _sds(one_chip, (f // 128,)))
+
+
+def test_smollm_swiglu_grad_compiles_unpadded(one_chip):
+    """smollm-360m FFN (d_model 960, d_ff 2560, a mask of 128-wide blocks
+    as the model passes it) at 8192 tokens, two stacked layers in a
+    rematerialised scan as the model runs them:
+    every product's tiles fit the scoped VMEM also where XLA fuses the
+    call into the update of a stacked gradient, and nothing pads the
+    960-wide axis."""
+    from repro.kernels.pruned_matmul import pruned_swiglu
+    layers, t, d, f = 2, 8192, 960, 2560
+
+    def block(x, w):
+        return x + pruned_swiglu(x, *w, jnp.ones((f // 128,)),
+                                 interpret=False), None
+
+    def loss(x, wi, wg, wo):
+        y, _ = jax.lax.scan(jax.checkpoint(block), x, (wi, wg, wo))
+        return jnp.sum(y ** 2)
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2, 3)),
+                          _sds(one_chip, (1, t, d)),
+                          _sds(one_chip, (layers, d, f)),
+                          _sds(one_chip, (layers, d, f)),
+                          _sds(one_chip, (layers, f, d)))
+    assert " pad(" not in text
+
+
+@pytest.mark.parametrize("tokens", [777, 1000])
+def test_smollm_swiglu_compiles_at_unaligned_tokens(one_chip, tokens):
+    """A token count of 1024 or less, off the sublane grid or not, is one
+    token tile (a serving prefill's mb × prompt length): the forward and
+    the gradient, whose dw products contract over that tile read
+    transposed, compile at smollm-360m's widths with a pruned mask."""
+    from repro.kernels.pruned_matmul import pruned_swiglu
+    d, f = 960, 2560
+    mask = _sds(one_chip, (f // 128,))
+
+    def fwd(x, wi, wg, wo, mask):
+        return pruned_swiglu(x, wi, wg, wo, mask, interpret=False)
+
+    def loss(x, wi, wg, wo, mask):
+        return jnp.sum(fwd(x, wi, wg, wo, mask) ** 2)
+    args = (_sds(one_chip, (1, tokens, d)), _sds(one_chip, (d, f)),
+            _sds(one_chip, (d, f)), _sds(one_chip, (f, d)), mask)
+    _compiled_text(fwd, *args)
+    _compiled_text(jax.grad(loss, argnums=(0, 1, 2, 3)), *args)
+
+
+def test_whisper_gelu_mlp_grad_compiles(one_chip):
+    """whisper-large-v3's FFN (d_model 1280, d_ff 5120) over one 30 s
+    encoder window (1500 frames, padded to the 1536-row tile grid), with
+    a mask of 128-wide blocks."""
+    from repro.models.layers import gelu_mlp
+    t, d, f = 1500, 1280, 5120
+
+    def loss(x, w1, b1, w2, b2, mask):
+        return jnp.sum(gelu_mlp(x, w1, b1, w2, b2, mask, impl="pallas",
+                                interpret=False) ** 2)
+    _compiled_text(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                   _sds(one_chip, (1, t, d)), _sds(one_chip, (d, f)),
+                   _sds(one_chip, (f,)), _sds(one_chip, (f, d)),
+                   _sds(one_chip, (d,)), _sds(one_chip, (f // 128,)))
 
 
 def test_grouped_matmul_grad_compiles(one_chip):
