@@ -7,7 +7,9 @@ compute is issued — on TPU the saved time is the tile's matmul+softmax).
 
 Tiling: grid = (batch·heads, q_blocks, kv_blocks), kv innermost so the
 online-softmax accumulator lives in VMEM scratch across the kv sweep.
-Block shapes default to (128, 128) — MXU-aligned.  The block mask rides as
+Block shapes default to (128, 128) — MXU-aligned; a mask fixes them to its
+block, and where there is none ``tiles.choose_attention_tiles`` takes them
+from the call's shape (models/layers.py).  The block mask rides as
 scalar prefetch (a flat int32 vector in SMEM, like paged_attention's page
 table), and per-row statistics are ``[rows, 1]`` columns, so every VMEM
 block has a shape the TPU lowering accepts.

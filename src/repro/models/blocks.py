@@ -435,7 +435,8 @@ def _attn_fwd(x, wq, wk, wv, wo, *, cfg, mode, cache, pos,
                               block_mask=block_mask, kv_block=kv_block,
                               impl=kernel_impl)
         tiles = attention_tiles(block_mask, b, s, xkv.shape[1],
-                                causal=causal, kv_block=kv_block,
+                                causal=causal, head_dim=hd,
+                                itemsize=q.dtype.itemsize, kv_block=kv_block,
                                 sliding_window=cfg.sliding_window,
                                 impl=kernel_impl)
         if mode == "prefill" and cache is not None:

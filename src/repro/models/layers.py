@@ -236,23 +236,29 @@ def flash_attention(q, k, v, *, causal: bool, sliding_window: int = 0,
     return out
 
 
-def _kernel_mask(block_mask, sq, sk, kv_block):
-    """The Pallas kernels' block size, block counts and mask for a call:
-    (block, nqb, nkb, mask [b|1, h|1, nqb, nkb] or None for all tiles).
+def _kernel_mask(block_mask, sq, sk, kv_block, *, causal, head_dim,
+                 itemsize):
+    """The Pallas kernels' tiles, block counts and mask for a call:
+    (block_q, block_k, nqb, nkb, mask [b|1, h|1, nqb, nkb] or None for
+    all tiles).
 
-    Accepts the model's mask layouts ([h, nqb, nkb] or [b, h|1, nqb, nkb])
-    and edge-extends them to the kernels' block counts."""
-    block = kv_block if block_mask is not None else min(kv_block, 128)
-    nqb = -(-sq // block)
-    nkb = -(-sk // block)
+    A mask fixes the tiles to its block (``kv_block``); without one they
+    follow the call's shape (``choose_attention_tiles``).  Accepts the
+    model's mask layouts ([h, nqb, nkb] or [b, h|1, nqb, nkb]) and
+    edge-extends them to the kernels' block counts."""
     if block_mask is None:
-        return block, nqb, nkb, None
+        from repro.kernels.block_sparse_attention import (
+            choose_attention_tiles)
+        bq, bk = choose_attention_tiles(sq, sk, head_dim, causal, itemsize)
+        return bq, bk, -(-sq // bq), -(-sk // bk), None
+    nqb = -(-sq // kv_block)
+    nkb = -(-sk // kv_block)
     bm = block_mask if block_mask.ndim == 4 else block_mask[None]
     # trailing partial blocks reuse the last mask row/col (the scan path's
     # qb_ids gather clips the same way)
     qb = jnp.clip(jnp.arange(nqb), 0, bm.shape[2] - 1)
     kb = jnp.clip(jnp.arange(nkb), 0, bm.shape[3] - 1)
-    return block, nqb, nkb, bm[:, :, qb][:, :, :, kb]
+    return kv_block, kv_block, nqb, nkb, bm[:, :, qb][:, :, :, kb]
 
 
 def _pallas_attention(q, k, v, block_mask, causal, kv_block,
@@ -260,36 +266,43 @@ def _pallas_attention(q, k, v, block_mask, causal, kv_block,
     """Route through the Pallas block-sparse kernel (dense = all-ones mask)
     with the mask broadcast to the kernel's [b, hq, nqb, nkb]."""
     from repro.kernels.block_sparse_attention import block_sparse_attention
-    b, sq, hq, _ = q.shape
-    block, nqb, nkb, bm = _kernel_mask(block_mask, sq, k.shape[1], kv_block)
+    b, sq, hq, hd = q.shape
+    bq, bk, nqb, nkb, bm = _kernel_mask(
+        block_mask, sq, k.shape[1], kv_block, causal=causal, head_dim=hd,
+        itemsize=q.dtype.itemsize)
     if bm is None:
         bm = jnp.ones((b, hq, nqb, nkb), jnp.float32)
     else:
         bm = jnp.broadcast_to(bm, (b, hq, nqb, nkb)).astype(jnp.float32)
     if interpret is None:
         interpret = kernels.use_interpret()
-    return block_sparse_attention(q, k, v, bm, causal=causal, block_q=block,
-                                  block_k=block, interpret=interpret)
+    return block_sparse_attention(q, k, v, bm, causal=causal, block_q=bq,
+                                  block_k=bk, interpret=interpret)
 
 
 def attention_tiles(block_mask, b: int, sq: int, sk: int, *, causal: bool,
-                    kv_block: int = 512, sliding_window: int = 0,
+                    head_dim: int, itemsize: int, kv_block: int = 512,
+                    sliding_window: int = 0,
                     impl: str = "scan") -> jax.Array:
     """(query block, key block) tiles the Pallas kernels compute for one
     ``flash_attention`` call over ``b`` sequences, per head (heads share
     the mask): the tiles that pass the kernels' own gate (``tile_active``)
-    on the mask they receive, at their block size.  Each tile is computed
-    once by the forward kernel and once by each backward kernel.  0.0 where
-    the call does not run the Pallas kernels."""
+    on the mask they receive, at their tiles (``_kernel_mask``: the
+    query's ``head_dim`` and ``itemsize`` choose them where no mask
+    does).  Each tile is computed once by the forward kernel and once by
+    each backward kernel.  0.0 where the call does not run the Pallas
+    kernels."""
     if impl != "pallas" or sliding_window:
         return jnp.float32(0.0)
     from repro.kernels.block_sparse_attention.block_sparse_attention import (
         tile_active)
-    block, nqb, nkb, bm = _kernel_mask(block_mask, sq, sk, kv_block)
+    bq, bk, nqb, nkb, bm = _kernel_mask(block_mask, sq, sk, kv_block,
+                                        causal=causal, head_dim=head_dim,
+                                        itemsize=itemsize)
     live = tile_active(jnp.int32(1) if bm is None else bm.astype(jnp.int32),
                        jnp.arange(nqb)[:, None], jnp.arange(nkb)[None, :],
-                       causal=causal, block_q=block, block_k=block,
-                       kv_len=sk, sk_pad=nkb * block)
+                       causal=causal, block_q=bq, block_k=bk,
+                       kv_len=sk, sk_pad=nkb * bk)
     live = jnp.broadcast_to(live, (bm.shape[:2] if bm is not None
                                    else (1, 1)) + (nqb, nkb))
     # a mask shared by the batch counts once per sequence

@@ -238,9 +238,11 @@ def test_engine_counts_the_attention_tiles_of_its_steps(kind):
     the steps' sequences exactly.  Sparse: the mask sum of
     ``hash_block_mask``, which each layer's density reports (the density
     stat sums, over micro-batches, the mean over rows of the mask sum over
-    its causal tiles).  Dense: the Pallas kernels skip the tiles above the
-    diagonal, so each sequence costs nb(nb + 1)/2 tiles of 128 tokens."""
+    its causal tiles).  Dense: the Pallas kernels run the tiles
+    ``choose_attention_tiles`` gives the shape and skip those wholly above
+    the diagonal: nb(nb + 1)/2 a sequence at square tiles."""
     from repro.data.loader import DataConfig, make_loader
+    from repro.kernels.block_sparse_attention import choose_attention_tiles
     from repro.launch.engine import ElasticEngine
     from repro.pipeline.pipeline import PipelineShapes
     layers, num_micro, mb, seq, block, steps = 2, 2, 2, 256, 64, 2
@@ -263,7 +265,10 @@ def test_engine_counts_the_attention_tiles_of_its_steps(kind):
             want += int(np.rint(np.asarray(stats["attn_density"])
                                 * nb * (nb + 1) / 2 * mb).sum())
         else:
-            nb = seq // 128
-            want += layers * num_micro * mb * nb * (nb + 1) // 2
+            bq, bk = choose_attention_tiles(seq, seq, cfg.resolved_head_dim,
+                                            True, 4)
+            nq, nk = -(-seq // bq), -(-seq // bk)
+            want += layers * num_micro * mb * sum(
+                min(nk, (i * bq + bq - 1) // bk + 1) for i in range(nq))
     assert engine.attn_tiles_total() == want
     assert engine.attn_tiles_total() == want    # folded once, not twice

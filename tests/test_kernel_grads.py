@@ -108,6 +108,44 @@ def test_layers_flash_attention_pallas_matches_scan_grads():
                                        atol=2e-2, err_msg=name)
 
 
+# (queries, keys, causal): lengths off every candidate tile, so kv pads
+UNMASKED_CASES = [
+    pytest.param(600, 600, True, id="causal-600"),
+    pytest.param(600, 600, False, id="noncausal-600"),
+    pytest.param(333, 333, True, id="prefill-333"),
+    pytest.param(200, 700, False, id="cross-200x700"),
+]
+
+
+@pytest.mark.parametrize("sq,sk,causal", UNMASKED_CASES)
+def test_unmasked_pallas_attention_matches_reference(sq, sk, causal):
+    """The unmasked Pallas path at the tiles ``choose_attention_tiles``
+    gives the shape: output and the gradients of q, k and v against
+    ``attention_reference`` (GQA, kv padded to the tile)."""
+    from repro.models.layers import attention_reference
+    rng = np.random.RandomState(sq + sk + causal)
+    b, hq, hkv, d = 1, 2, 1, 64
+    q = jnp.asarray(rng.randn(b, sq, hq, d) * 0.4, jnp.float32)
+    k = jnp.asarray(rng.randn(b, sk, hkv, d) * 0.4, jnp.float32)
+    v = jnp.asarray(rng.randn(b, sk, hkv, d) * 0.4, jnp.float32)
+    g = jnp.asarray(rng.randn(b, sq, hq, d), jnp.float32)
+
+    def pallas(q, k, v):
+        return flash_attention(q, k, v, causal=causal, impl="pallas",
+                               interpret=True)
+
+    def reference(q, k, v):
+        return attention_reference(q, k, v, causal=causal)
+    # float32 both ways: rounding over at most a tile's 1024 terms
+    np.testing.assert_allclose(np.asarray(pallas(q, k, v)),
+                               np.asarray(reference(q, k, v)), atol=1e-6)
+    gp = jax.grad(lambda *a: jnp.sum(pallas(*a) * g), (0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(reference(*a) * g), (0, 1, 2))(q, k, v)
+    for a, b_, name in zip(gp, gr, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=1e-5, atol=1e-5, err_msg=name)
+
+
 @pytest.mark.parametrize("mask_axis", ["n", "k"])
 @pytest.mark.parametrize("density", [1.0, 0.5, 0.25])
 def test_pruned_matmul_grad_parity(mask_axis, density):

@@ -1,5 +1,7 @@
 """Pallas kernel validation: interpret-mode (CPU) vs the pure-jnp ref.py
 oracles, swept over shapes / dtypes / sparsity levels."""
+import itertools
+
 import numpy as np
 import pytest
 
@@ -7,7 +9,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.block_sparse_attention import (block_sparse_attention,
-                                                  block_sparse_attention_ref)
+                                                  block_sparse_attention_ref,
+                                                  choose_attention_tiles)
 from repro.kernels.pruned_matmul import (pruned_matmul, pruned_matmul_ref,
                                          pruned_swiglu, pruned_swiglu_ref)
 from repro.kernels.pruned_matmul.pruned_matmul import (FULL_AXIS,
@@ -218,30 +221,108 @@ def test_pruned_matmul_matches_model_semantics():
                                atol=1e-4)
 
 
+# (sq, sk, head_dim, causal) -> (block_q, block_k): the dense benchmark
+# cell, gpt-paper's training length, whisper-large-v3's encoder window and
+# its decoder's cross-attention onto it, a short unaligned prefill
+TILE_CHOICES = [
+    pytest.param((8192, 8192, 64, True), (1024, 512), id="smollm-dense8k"),
+    pytest.param((2048, 2048, 32, True), (1024, 512), id="gpt-paper-2048"),
+    pytest.param((1500, 1500, 64, False), (512, 512), id="whisper-enc-1500"),
+    pytest.param((448, 1500, 64, False), (512, 512), id="cross-448x1500"),
+    pytest.param((333, 333, 64, True), (512, 512), id="prefill-333"),
+]
+
+
+@pytest.mark.parametrize("shape,want", TILE_CHOICES)
+def test_choose_attention_tiles_at_the_callers_shapes(shape, want):
+    assert tuple(choose_attention_tiles(*shape)) == want
+
+
+def test_attention_tile_choices_fit_the_scoped_vmem():
+    """Every choice fits ``VMEM_BUDGET`` by ``attention_vmem``, which
+    rejects the 1024 x 1024 tile that overflows the 16 MiB of scoped VMEM
+    in the model's stacked layer scan (``tests/test_tpu_compile.py``
+    compiles the choices there)."""
+    from repro.kernels.block_sparse_attention.tiles import (VMEM_BUDGET,
+                                                            attention_vmem)
+    lengths = (1, 100, 333, 448, 1000, 1500, 2048, 4096, 8192, 32768)
+    for sq, sk, hd, causal, itemsize in itertools.product(
+            lengths, lengths, (32, 64, 128, 256), (True, False), (2, 4)):
+        t = choose_attention_tiles(sq, sk, hd, causal, itemsize)
+        assert attention_vmem(*t, hd, itemsize) <= VMEM_BUDGET, (
+            sq, sk, hd, t)
+    assert attention_vmem(1024, 1024, 64, 4) > VMEM_BUDGET
+
+
+@pytest.mark.parametrize("block", [64, 512])
+@pytest.mark.parametrize("masked", [True, False])
+def test_pallas_attention_tiles_follow_the_mask_else_the_chooser(
+        monkeypatch, masked, block):
+    """A masked call runs the kernels at the mask's block, as it always
+    did, and counts the mask's live tiles; an unmasked one runs at
+    ``choose_attention_tiles``' tiles."""
+    import repro.kernels.block_sparse_attention as bsa
+    from repro.models.layers import attention_tiles, flash_attention
+    seen = {}
+
+    def spy(q, k, v, m, *, causal, block_q, block_k, interpret):
+        seen.update(block_q=block_q, block_k=block_k, nb=m.shape[2:])
+        return q
+    monkeypatch.setattr(bsa, "block_sparse_attention", spy)
+    b, s, h, hd = 2, 2048, 2, 64
+    q = jnp.zeros((b, s, h, hd), jnp.float32)
+    mask = None
+    if masked:
+        rng = np.random.RandomState(block)
+        mask = jnp.asarray(rng.rand(b, 1, s // block, s // block) > 0.5,
+                           jnp.float32)
+    flash_attention(q, q, q, causal=True, block_mask=mask, kv_block=block,
+                    impl="pallas")
+    tiles = attention_tiles(mask, b, s, s, causal=True, head_dim=hd,
+                            itemsize=4, kv_block=block, impl="pallas")
+    if masked:
+        assert (seen["block_q"], seen["block_k"]) == (block, block)
+        assert seen["nb"] == (s // block, s // block)
+        nb = s // block
+        below = np.tril(np.ones((nb, nb)))
+        assert float(tiles) == float(np.sum(np.asarray(mask) * below))
+    else:
+        want = choose_attention_tiles(s, s, hd, True, 4)
+        assert (seen["block_q"], seen["block_k"]) == tuple(want)
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_attention_tiles_count_the_kernels_live_tiles(causal):
     """``layers.attention_tiles`` counts, per head, the tiles the Pallas
     kernels compute: on a hash mask, the mask's own sum (the mask is
     already causal, and at equal blocks the kernels' causal gate keeps
     exactly the tiles on or below the diagonal); without a mask, at the
-    kernels' 128-token blocks, every causal tile of every sequence, or
-    every tile when not causal; nothing on the scan path."""
+    tiles ``choose_attention_tiles`` gives the call, every causal tile of
+    every sequence, or every tile when not causal; nothing on the scan
+    path."""
     from repro.models.blocks import hash_block_mask
     from repro.models.layers import attention_tiles
-    b, s, block = 3, 512, 64
+    b, s, block, hd = 3, 512, 64, 32
     x = jnp.asarray(np.random.RandomState(1).randn(b, s, 32), jnp.float32)
     mask, density = hash_block_mask(x, nbuckets=4, block=block,
                                     causal=causal)
-    got = attention_tiles(mask, b, s, s, causal=causal, kv_block=block,
-                          impl="pallas")
+    got = attention_tiles(mask, b, s, s, causal=causal, head_dim=hd,
+                          itemsize=4, kv_block=block, impl="pallas")
     nb = s // block
     assert float(got) == float(jnp.sum(mask))
     causal_tiles = nb * (nb + 1) / 2 if causal else nb * nb
     assert float(got) == pytest.approx(float(density) * causal_tiles * b)
-    dense = attention_tiles(None, b, s, s, causal=causal, kv_block=512,
-                            impl="pallas")
-    n128 = s // 128
-    assert float(dense) == b * (n128 * (n128 + 1) // 2 if causal
-                                else n128 * n128)
-    assert float(attention_tiles(mask, b, s, s, causal=causal,
-                                 kv_block=block, impl="scan")) == 0.0
+    for seq in (s, 8192):
+        bq, bk = choose_attention_tiles(seq, seq, hd, causal, 4)
+        dense = attention_tiles(None, b, seq, seq, causal=causal,
+                                head_dim=hd, itemsize=4, kv_block=512,
+                                impl="pallas")
+        nq, nk = seq // bq, seq // bk
+        want = (sum(min(nk, (i * bq + bq - 1) // bk + 1) for i in range(nq))
+                if causal else nq * nk)
+        assert float(dense) == b * want
+        if bq == bk:
+            assert want == (nq * (nq + 1) // 2 if causal else nq * nq)
+    assert float(attention_tiles(mask, b, s, s, causal=causal, head_dim=hd,
+                                 itemsize=4, kv_block=block,
+                                 impl="scan")) == 0.0

@@ -77,6 +77,45 @@ def test_block_sparse_attention_grad_compiles(one_chip, arch):
                    *_bsa_args(one_chip, arch))
 
 
+# (tokens, d_model, heads, kv heads, head_dim, causal): smollm-360m at the
+# benchmark's 8192, gpt-paper at 2048, whisper-large-v3's encoder window
+UNMASKED_ATTENTION = {"smollm-360m-8k": (8192, 960, 15, 5, 64, True),
+                      "gpt-paper": (SEQ, 1024, 32, 32, 32, True),
+                      "whisper-encoder": (1500, 1280, 20, 20, 64, False)}
+
+
+@pytest.mark.parametrize("arch", sorted(UNMASKED_ATTENTION))
+def test_unmasked_attention_compiles_in_stacked_layers(one_chip, arch):
+    """The unmasked Pallas path at the tiles ``choose_attention_tiles``
+    gives the shape, forward and gradient, in two stacked layers of a
+    rematerialised scan as the model runs them: where XLA fuses a kernel
+    into its neighbours there, it holds it to the 16 MiB of scoped VMEM."""
+    from repro.models.layers import flash_attention
+    layers, (t, d, hq, hkv, hd, causal) = 2, UNMASKED_ATTENTION[arch]
+
+    def attend(x, w):
+        wq, wk, wv, wo = w
+        q = (x @ wq).reshape(1, t, hq, hd)
+        k = (x @ wk).reshape(1, t, hkv, hd)
+        v = (x @ wv).reshape(1, t, hkv, hd)
+        return flash_attention(q, k, v, causal=causal, impl="pallas",
+                               interpret=False).reshape(1, t, hq * hd) @ wo
+
+    def block(x, w):
+        return x + attend(x, w), None
+
+    def loss(x, *w):
+        y, _ = jax.lax.scan(jax.checkpoint(block), x, w)
+        return jnp.sum(y ** 2)
+    args = (_sds(one_chip, (1, t, d)),
+            _sds(one_chip, (layers, d, hq * hd)),
+            _sds(one_chip, (layers, d, hkv * hd)),
+            _sds(one_chip, (layers, d, hkv * hd)),
+            _sds(one_chip, (layers, hq * hd, d)))
+    _compiled_text(lambda x, *w: attend(x, [a[0] for a in w]), *args)
+    _compiled_text(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), *args)
+
+
 def test_pruned_swiglu_grad_compiles(one_chip):
     """gpt-paper FFN (d_model 1024, d_ff 4096, 32 prune blocks of 128)."""
     from repro.kernels.pruned_matmul import pruned_swiglu
